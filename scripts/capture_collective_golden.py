@@ -9,7 +9,8 @@ with ``schedule="barrier"``) must reproduce bitwise — see
 ``tests/test_engine_parity.py::test_collective_golden_parity``.
 
 Regenerating this file is only legitimate for PRs that intentionally change
-collective behaviour.
+collective behaviour, or when a JAX upgrade changes the random stream (JAX
+0.5 turned ``jax_threefry_partitionable`` on by default).
 """
 import json
 import pathlib

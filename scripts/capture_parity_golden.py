@@ -7,7 +7,9 @@ refactors (compact routing tables, free-list pool, donated buffers): the
 rebuilt ``backend="xla"`` engine must reproduce these numbers bitwise.
 
 To regenerate (only legitimate when a PR *intentionally* changes simulated
-behaviour, which parity-preserving perf work must not):
+behaviour, which parity-preserving perf work must not, or when a JAX
+upgrade changes the random stream — JAX 0.5 turned
+``jax_threefry_partitionable`` on by default):
 
     PYTHONPATH=src python scripts/capture_parity_golden.py
 """
@@ -20,7 +22,6 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 import numpy as np
 
 from repro.core import mrls, build_tables
-from repro.core.routing import POLICIES
 from repro.simulator.engine import Simulator, SimConfig, Traffic
 
 OUT = pathlib.Path(__file__).resolve().parents[1] / "tests" / "golden" / \
@@ -28,6 +29,7 @@ OUT = pathlib.Path(__file__).resolve().parents[1] / "tests" / "golden" / \
 
 FABRIC = {"n_leaves": 14, "u": 3, "d": 3, "seed": 0}
 WARM, MEASURE = 60, 120
+POLICIES = ("polarized", "minimal_adaptive", "ksp", "ugal", "valiant")
 
 
 def main():

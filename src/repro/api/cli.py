@@ -408,6 +408,8 @@ register_subcommand(Subcommand(
 # dispatch
 # ---------------------------------------------------------------------- #
 def main(argv: Optional[List[str]] = None) -> int:
+    from ..runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     parser = argparse.ArgumentParser(prog="python -m repro.api",
                                      description=__doc__.splitlines()[0])
     sub = parser.add_subparsers(dest="command", required=True)

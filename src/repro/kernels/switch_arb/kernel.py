@@ -22,8 +22,10 @@ cannot fuse into the arbitration kernel: between the two stages the engine
 gathers the selected head packets and their attributes from state arrays
 (data-dependent addresses spanning the whole pool), which is exactly the
 irregular access Pallas blocks are not shaped for — see DESIGN.md.  Its
-``[P, V]`` trailing block is left unpadded (V is 4; a production TPU port
-would flatten to a 128-lane ``[P * V]`` layout).
+``[P, V]`` trailing block is left unpadded (V is 4).  Mosaic accepts it,
+but the TPU tiles pad V to 128 lanes, so the compiled kernel at 104,976
+endpoints holds ~0.5 GB of padded temporaries; a 128-lane ``[P * V]``
+layout would remove them.
 
 All randomness is drawn by the caller (``jax.random`` on the host stream)
 and passed in as tensors, which is what makes kernel, oracle, and inline
@@ -100,7 +102,10 @@ def _arb_kernel(occ_ref, der_ref, mask_ref, tie_ref, route_ref, rnd_ref,
     can = (route_ref[...] > 0) & (jnp.min(score, axis=-1) < BIG)
     prio = jnp.where(can, (rnd_ref[...] << 23) | lo_ref[...], -1)
     p_ids = jax.lax.broadcasted_iota(jnp.int32, score.shape, 2)
-    onehot = (port[:, :, None] == p_ids) & can[:, :, None]      # [BN,R,P]
+    # Mosaic cannot add a trailing unit dim to a bool vector, so ``can``
+    # crosses into 3-D as int32
+    can3 = can.astype(jnp.int32)[:, :, None] > 0
+    onehot = (port[:, :, None] == p_ids) & can3                 # [BN,R,P]
     seg = jnp.max(jnp.where(onehot, prio[:, :, None], -1), axis=1)
     seg_at = jnp.sum(jnp.where(onehot, seg[:, None, :], 0), axis=-1)
     port_ref[...] = port

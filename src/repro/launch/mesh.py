@@ -12,13 +12,10 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
-
-from .._jax_compat import AxisType  # also polyfills jax.set_mesh/shard_map
+from jax.sharding import AxisType
 
 
 def _mesh_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

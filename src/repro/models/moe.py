@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .common import ParamSpec, GATED_ACTS
-from .. import _jax_compat  # noqa: F401 — polyfills jax.shard_map
 
 
 __all__ = ["MoECfg", "moe_specs", "moe_apply"]

@@ -15,8 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from .. import _jax_compat  # noqa: F401 — polyfills jax.shard_map
-
 
 
 def compress(g, ef):

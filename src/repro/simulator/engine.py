@@ -66,6 +66,7 @@ per-phase host loop bitwise, ``schedule="window"`` pipelines rounds.
 from __future__ import annotations
 
 import contextlib
+import copy
 import dataclasses
 import functools
 import warnings
@@ -91,11 +92,15 @@ LATENCY_QS = (0.5, 0.99, 0.999, 0.9999)
 
 @contextlib.contextmanager
 def _quiet_cpu_donation():
-    """Buffer donation is a no-op on CPU backends; jax warns once per
-    compile, which would drown test output for the (CPU-only) tier-1
-    suite.  Scoped to this engine's own compiles — the process-global
-    filter is left alone so callers' unrelated donation diagnostics
-    still surface."""
+    """Silence "Some donated buffers were not usable" on the CPU backend,
+    where jax warns once per compile and would drown the test output.  On
+    an accelerator the warning is the one sign that a donated state or
+    table is being copied instead of updated in place, so it stays
+    visible there.  Scoped to this engine's own compiles — the
+    process-global filter is left alone."""
+    if jax.default_backend() != "cpu":
+        yield
+        return
     with warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
@@ -218,9 +223,9 @@ class Simulator:
         self.tables, self.cfg = tables, cfg
         # failure machinery is a *static* branch: with no schedule (or an
         # empty one) every step traces exactly as before — routing tables
-        # stay closure-captured constants and no live masks ride in the
-        # state, so the parity goldens are bitwise-untouched.  With a
-        # schedule, the tables move into the state (``tbl_min`` /
+        # are read-only arguments of the jitted loops and no live masks
+        # ride in the state, so the parity goldens are bitwise-untouched.
+        # With a schedule, the tables move into the state (``tbl_min`` /
         # ``tbl_away`` / ``tbl_dist`` + ``link_up`` / ``switch_up``) so
         # ``update_tables`` can rewrite them mid-run without recompiling.
         self.failures = failures
@@ -275,6 +280,22 @@ class Simulator:
         self._init_requester_geometry(topo)
         self._sharded_cache: dict = {}
         self._closed = False
+
+    # The routing tables are the largest device arrays (~0.9 GB at 104,976
+    # endpoints).  The jitted loops take them as (undonated) arguments and
+    # trace the step on a copy of the simulator bound to those arguments,
+    # so they never become constants of the compiled program, which would
+    # carry them inside every executable and persistent-cache entry.
+    _TABLE_ATTRS = ("min_mask", "away_mask", "dist")
+
+    def _tables(self) -> dict:
+        return {k: getattr(self, k) for k in self._TABLE_ATTRS}
+
+    def _bound(self, tb: dict) -> "Simulator":
+        """Shallow copy of ``self`` whose tables are the traced ``tb``."""
+        sim = copy.copy(self)
+        sim.__dict__.update(tb)
+        return sim
 
     def _build_device_masks(self, tables: RoutingTables):
         """Device mask tables ``[N1*N, W]``, assembled from streamed leaf
@@ -1095,23 +1116,27 @@ class Simulator:
     # runtime instead of double-buffering every array per chunk.  The input
     # dict is CONSUMED — callers must keep using the returned state.
     # ------------------------------------------------------------------ #
-    @functools.partial(jax.jit, static_argnums=(0, 2, 3), donate_argnums=(1,))
-    def _run_chunk_jit(self, st, traffic: Traffic, n_slots: int):
+    @functools.partial(jax.jit, static_argnums=(0, 3, 4), donate_argnums=(1,))
+    def _run_chunk_jit(self, st, tb, traffic: Traffic, n_slots: int):
+        sim = self._bound(tb)
+
         def body(carry, _):
-            return self._step(carry, traffic), None
+            return sim._step(carry, traffic), None
         st, _ = jax.lax.scan(body, st, None, length=n_slots)
         return st
 
     def run_chunk(self, st, traffic: Traffic, n_slots: int):
         """Advance ``n_slots`` slots.  ``st`` is donated (consumed)."""
         with _quiet_cpu_donation():
-            return self._run_chunk_jit(st, traffic, n_slots)
+            return self._run_chunk_jit(st, self._tables(), traffic, n_slots)
 
-    @functools.partial(jax.jit, static_argnums=(0, 2, 3), donate_argnums=(1,))
-    def _run_chunk_batch_jit(self, st, traffic: Traffic, n_slots: int):
+    @functools.partial(jax.jit, static_argnums=(0, 3, 4), donate_argnums=(1,))
+    def _run_chunk_batch_jit(self, st, tb, traffic: Traffic, n_slots: int):
+        sim = self._bound(tb)
+
         def one(s):
             def body(carry, _):
-                return self._step(carry, traffic), None
+                return sim._step(carry, traffic), None
             return jax.lax.scan(body, s, None, length=n_slots)[0]
         return jax.vmap(one)(st)
 
@@ -1119,7 +1144,8 @@ class Simulator:
         """``run_chunk`` vmapped over a leading ``[R]`` replica axis.
         ``st`` is donated (consumed)."""
         with _quiet_cpu_donation():
-            return self._run_chunk_batch_jit(st, traffic, n_slots)
+            return self._run_chunk_batch_jit(st, self._tables(), traffic,
+                                             n_slots)
 
     # ------------------------------------------------------------------ #
     # sharded execution (the repro.parallel.sharding simulator profile)
@@ -1155,19 +1181,22 @@ class Simulator:
         cached = self._sharded_cache.get(key)
         if cached is not None:
             return cached
-        from .. import _jax_compat  # noqa: F401 — polyfills jax.shard_map
+        from jax.sharding import PartitionSpec as P
         specs = dict(spec_items)
         # shared (replicated) entries ride the inner vmap unbatched
         axes = {k: 0 if (len(p) and p[0] == replica_axis) else None
                 for k, p in specs.items()}
 
-        def chunk(s):
+        def chunk(s, tb):
+            sim = self._bound(tb)
+
             def body(carry, _):
-                return self._step(carry, traffic), None
+                return sim._step(carry, traffic), None
             return jax.lax.scan(body, s, None, length=n_slots)[0]
 
-        local = jax.vmap(chunk, in_axes=(axes,), out_axes=axes)
-        shmapped = jax.shard_map(local, mesh=mesh, in_specs=(specs,),
+        # tables are replicated on every device and unbatched
+        local = jax.vmap(chunk, in_axes=(axes, None), out_axes=axes)
+        shmapped = jax.shard_map(local, mesh=mesh, in_specs=(specs, P()),
                                  out_specs=specs, check_vma=False)
         fn = jax.jit(shmapped, donate_argnums=(0,))
         self._sharded_cache[key] = fn
@@ -1203,7 +1232,7 @@ class Simulator:
         fn = self._sharded_chunk_fn(traffic, n_slots, sharder.mesh, axis,
                                     tuple(sorted(specs.items())))
         with _quiet_cpu_donation():
-            return fn(st)
+            return fn(st, self._tables())
 
     def state_shardings(self, st, sharder) -> dict:
         """Per-entry :class:`NamedSharding` for the per-switch layout.
@@ -1245,9 +1274,9 @@ class Simulator:
         return {k: jax.device_put(jnp.asarray(v), shardings[k])
                 for k, v in st.items()}
 
-    @functools.partial(jax.jit, static_argnums=(0, 2, 4, 5),
+    @functools.partial(jax.jit, static_argnums=(0, 3, 5, 6),
                        donate_argnums=(1,))
-    def _completion_loop(self, st, traffic: Traffic, expected,
+    def _completion_loop(self, st, tb, traffic: Traffic, expected,
                          chunk: int, max_slots: int):
         """Device-side completion detection: a ``lax.while_loop`` over
         ``chunk``-slot scans that stops once every replica has ejected
@@ -1260,7 +1289,8 @@ class Simulator:
         the step is vmapped when a replica axis is present.
         """
         batched = st["ejected"].ndim == 1
-        step = lambda s: self._step(s, traffic)
+        sim = self._bound(tb)
+        step = lambda s: sim._step(s, traffic)
         if batched:
             step = jax.vmap(step)
         expected = jnp.asarray(expected, jnp.int32)
@@ -1283,10 +1313,11 @@ class Simulator:
         done0 = jnp.full_like(st["ejected"], -1)
         return jax.lax.while_loop(cond, chunk_body, (st, done0))
 
-    @functools.partial(jax.jit, static_argnums=(0, 3, 4, 5, 6),
+    @functools.partial(jax.jit, static_argnums=(0, 4, 5, 6, 7, 8),
                        donate_argnums=(1, 2))
-    def _completion_loop_bounded(self, st, done, traffic: Traffic, expected,
-                                 chunk: int, max_slots: int, budget: int):
+    def _completion_loop_bounded(self, st, done, tb, traffic: Traffic,
+                                 expected, chunk: int, max_slots: int,
+                                 budget: int):
         """:meth:`_completion_loop` with a chunk *budget*: runs at most
         ``budget`` chunk bodies, then returns control to the host — the
         checkpointable chunk boundary.  The chunk body is byte-for-byte
@@ -1296,7 +1327,8 @@ class Simulator:
         resumed run keeps the exact completion slots already recorded.
         """
         batched = st["ejected"].ndim == 1
-        step = lambda s: self._step(s, traffic)
+        sim = self._bound(tb)
+        step = lambda s: sim._step(s, traffic)
         if batched:
             step = jax.vmap(step)
         expected = jnp.asarray(expected, jnp.int32)
@@ -1782,14 +1814,14 @@ class Simulator:
         st = {k: jnp.asarray(v) for k, v in st.items()}
         with _quiet_cpu_donation():
             if budget_chunks is None:
-                st, done = self._completion_loop(st, traffic, expected,
-                                                 chunk, max_slots)
+                st, done = self._completion_loop(st, self._tables(), traffic,
+                                                 expected, chunk, max_slots)
             else:
                 done = (jnp.full_like(st["ejected"], -1) if done is None
                         else jnp.asarray(done, jnp.int32))
                 st, done = self._completion_loop_bounded(
-                    st, done, traffic, expected, chunk, max_slots,
-                    int(budget_chunks))
+                    st, done, self._tables(), traffic, expected, chunk,
+                    max_slots, int(budget_chunks))
         done = np.asarray(done)
         final = np.asarray(st["slot"])
         slots = np.where(done >= 0, done, final)
@@ -1877,9 +1909,9 @@ class Simulator:
         batch.update(shared)
         return batch
 
-    @functools.partial(jax.jit, static_argnums=(0, 2, 3, 4),
+    @functools.partial(jax.jit, static_argnums=(0, 3, 4, 5),
                        donate_argnums=(1,))
-    def _program_loop(self, st, traffic: Traffic, chunk: int,
+    def _program_loop(self, st, tb, traffic: Traffic, chunk: int,
                       max_slots: int):
         """Device-side program executor: one ``lax.while_loop`` drives all
         phases of all replicas — the phase counter, per-phase ejection
@@ -1887,8 +1919,9 @@ class Simulator:
         R-replica, P-phase collective is one device computation with zero
         per-phase host round-trips."""
         batched = st["ejected"].ndim == 1
-        step = lambda s: self._step(s, traffic, chunk=chunk,
-                                    max_slots=max_slots)
+        sim = self._bound(tb)
+        step = lambda s: sim._step(s, traffic, chunk=chunk,
+                                   max_slots=max_slots)
         if batched:
             # replica-invariant schedule arrays ride unbatched
             # (in_axes/out_axes None): one shared device copy, no R-fold
@@ -1915,9 +1948,9 @@ class Simulator:
 
         return jax.lax.while_loop(cond, chunk_body, st)
 
-    @functools.partial(jax.jit, static_argnums=(0, 2, 3, 4, 5),
+    @functools.partial(jax.jit, static_argnums=(0, 3, 4, 5, 6),
                        donate_argnums=(1,))
-    def _program_loop_bounded(self, st, traffic: Traffic, chunk: int,
+    def _program_loop_bounded(self, st, tb, traffic: Traffic, chunk: int,
                               max_slots: int, budget: int):
         """:meth:`_program_loop` with a chunk *budget*: at most ``budget``
         chunk bodies per call, then control returns to the host — the
@@ -1929,8 +1962,9 @@ class Simulator:
         ``run_program`` bitwise.
         """
         batched = st["ejected"].ndim == 1
-        step = lambda s: self._step(s, traffic, chunk=chunk,
-                                    max_slots=max_slots)
+        sim = self._bound(tb)
+        step = lambda s: sim._step(s, traffic, chunk=chunk,
+                                   max_slots=max_slots)
         if batched:
             axes = {k: None if st[k].ndim == self._PROG_SHARED.get(k, -1)
                     else 0 for k in st}
@@ -2000,10 +2034,11 @@ class Simulator:
         st = {k: jnp.asarray(v) for k, v in st.items()}
         with _quiet_cpu_donation():
             if budget_chunks is None:
-                st = self._program_loop(st, traffic, chunk, max_slots)
+                st = self._program_loop(st, self._tables(), traffic, chunk,
+                                        max_slots)
             else:
-                st = self._program_loop_bounded(st, traffic, chunk,
-                                                max_slots,
+                st = self._program_loop_bounded(st, self._tables(), traffic,
+                                                chunk, max_slots,
                                                 int(budget_chunks))
         done = np.asarray(st["phase_done"])
         ok = np.asarray(st["phase_ok"])

@@ -5,14 +5,6 @@ import sys
 # and benches must see 1 device (the dry-run sets 512 itself).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # container without hypothesis: use deterministic shim
-    sys.path.insert(0, os.path.dirname(__file__))
-    from _hypothesis_compat import install as _install_hypothesis
-
-    _install_hypothesis()
-
 import jax  # noqa: E402
 import pytest  # noqa: E402
 

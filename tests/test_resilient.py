@@ -424,7 +424,14 @@ def test_sigkill_resume_bitwise(tmp_path):
     src = _CHILD_SRC.format(src=str(root / "src"), spec=str(spec),
                             ckpt=ckpt)
     proc = subprocess.Popen([_PY, "-c", src])
-    time.sleep(4.0)                   # inside the run on any CI host
+    # kill inside the run: after the child has written its spec, which a
+    # loaded host can take well past a fixed sleep to reach
+    started = pathlib.Path(ckpt) / "experiment.json"
+    deadline = time.monotonic() + 300
+    while (not started.exists() and proc.poll() is None
+           and time.monotonic() < deadline):
+        time.sleep(0.2)
+    time.sleep(1.0)
     if proc.poll() is None:
         os.kill(proc.pid, signal.SIGKILL)
     proc.wait()
