@@ -1,0 +1,9 @@
+"""Device time of the step's ``vc_prearb`` scope, VC pre-arbitration: self
+time (``simbench.phases.self_times``) of the operations of the traced
+stretch whose op path holds the scope, per device, per simulated slot of
+the stretch (``loop_ms_per_slot``'s slots)."""
+from simbench import phases
+
+
+def read(run):
+    return phases.phase_ms_per_slot(run, "vc_prearb")

@@ -14,6 +14,7 @@ from typing import Callable, Optional
 
 from ..core import TOPOLOGY_BUILDERS
 from ..core.topology import Topology
+from ..runtime import tracing
 from ..workloads.patterns import pattern_kinds
 from ..workloads.programs import PROGRAM_BUILDERS
 from .specs import NetworkSpec
@@ -68,4 +69,5 @@ def build_network(spec: NetworkSpec) -> Topology:
         raise KeyError(
             f"unknown topology family {spec.family!r}; known: "
             f"{topology_families()}") from None
-    return builder(**spec.param_dict())
+    with tracing.span("topology.build"):
+        return builder(**spec.param_dict())

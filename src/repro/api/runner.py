@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from ..core import build_tables
+from ..runtime import tracing
 from ..simulator.engine import Simulator, Traffic
 from ..workloads import build_collective_program, compile_program
 from .registry import build_network
@@ -158,7 +159,8 @@ def _admitted_masks(experiment: Experiment) -> str:
     AdmissionError` with actionable alternatives when nothing fits;
     ``REPRO_ADMISSION=warn|off`` relaxes the gate."""
     from .admission import check_admission
-    return check_admission(experiment).masks
+    with tracing.span("api.admission"):
+        return check_admission(experiment).masks
 
 
 class SimulatorCache:
@@ -283,11 +285,12 @@ def _collective_program(sim: Simulator, exp: Experiment):
     compiles its shifted-exchange rounds under the requested mode.
     """
     w = exp.workload
-    prog = build_collective_program(
-        w.pattern, sim.S, rounds=w.rounds, ranks=w.ranks,
-        vec_packets=w.vec_packets)
-    return compile_program(prog, schedule=w.schedule or "barrier",
-                           window=w.window)
+    with tracing.span("runner.prepare"):
+        prog = build_collective_program(
+            w.pattern, sim.S, rounds=w.rounds, ranks=w.ranks,
+            vec_packets=w.vec_packets)
+        return compile_program(prog, schedule=w.schedule or "barrier",
+                               window=w.window)
 
 
 def _run_collective(sim: Simulator, exp: Experiment) -> Result:
@@ -513,16 +516,17 @@ def run(experiment: Experiment, *,
     actionable :class:`~repro.api.admission.AdmissionError` otherwise
     (``REPRO_ADMISSION=warn|off`` relaxes the gate).
     """
-    masks = _admitted_masks(experiment)
-    owns = cache is None
-    sim = (_make_simulator(experiment.network, experiment.route, masks)
-           if owns
-           else cache.get(experiment.network, experiment.route, masks))
-    try:
-        return _run_on(sim, experiment)
-    finally:
-        if owns:
-            sim.close()
+    with tracing.span("api.run", answer=int(experiment.seed)):
+        masks = _admitted_masks(experiment)
+        owns = cache is None
+        sim = (_make_simulator(experiment.network, experiment.route, masks)
+               if owns
+               else cache.get(experiment.network, experiment.route, masks))
+        try:
+            return _run_on(sim, experiment)
+        finally:
+            if owns:
+                sim.close()
 
 
 def run_all(experiments, *, cache: Optional[SimulatorCache] = None,

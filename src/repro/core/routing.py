@@ -18,6 +18,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..runtime import tracing
 from .topology import Topology
 
 __all__ = [
@@ -430,17 +431,19 @@ def build_tables(topo: Topology, full: bool = False, *,
     if masks not in MASK_LAYOUTS:
         raise ValueError(f"unknown mask layout {masks!r}; expected one of "
                          f"{MASK_LAYOUTS}")
-    dist_leaf = bfs_distances(topo, topo.leaf_ids)
-    dist_full = bfs_distances(topo, np.arange(topo.n_switches)) if full else None
-    if masks == "auto":
-        dense_bytes = mask_table_bytes(topo.n_leaves, topo.n_switches,
-                                       topo.max_ports)
-        masks = "dense" if dense_bytes <= DENSE_MASK_LIMIT else "blocked"
-    if masks == "dense":
-        min_mask, away_mask = pack_port_masks(dist_leaf, topo.nbrs,
-                                              leaf_block)
-    else:
-        min_mask = away_mask = None
+    with tracing.span("routing.tables"):
+        dist_leaf = bfs_distances(topo, topo.leaf_ids)
+        dist_full = (bfs_distances(topo, np.arange(topo.n_switches))
+                     if full else None)
+        if masks == "auto":
+            dense_bytes = mask_table_bytes(topo.n_leaves, topo.n_switches,
+                                           topo.max_ports)
+            masks = "dense" if dense_bytes <= DENSE_MASK_LIMIT else "blocked"
+        if masks == "dense":
+            min_mask, away_mask = pack_port_masks(dist_leaf, topo.nbrs,
+                                                  leaf_block)
+        else:
+            min_mask = away_mask = None
     return RoutingTables(topo, dist_leaf, topo.leaf_rank(), dist_full,
                          min_mask, away_mask, mask_layout=masks,
                          leaf_block=leaf_block)

@@ -86,6 +86,7 @@ def vc_prearb(qlen, rand, block_n: int = 8, interpret: bool = False):
             jax.ShapeDtypeStruct((np_, p), jnp.int32),
         ),
         interpret=interpret,
+        name="vc_prearb",
     )(qlen, rand)
     return sel[:n], has[:n]
 
@@ -149,5 +150,6 @@ def switch_arbitrate(occ, deroute, mask, tie, route, rnd, lo, *,
             jax.ShapeDtypeStruct((np_, pp), jnp.int32),
         ),
         interpret=interpret,
+        name="switch_arbitrate",
     )(occ, deroute, mask, tie, route, rnd, lo)
     return port[:n, :r], win[:n, :r], seg[:n, :p]

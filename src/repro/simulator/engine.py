@@ -77,6 +77,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.routing import RoutingTables
+from ..runtime import tracing
 from ..workloads.patterns import (ARRIVAL_PATTERNS, BERNOULLI_PATTERNS,
                                   bounded_pareto_mean, check_arrival,
                                   check_pattern)
@@ -88,6 +89,12 @@ BACKENDS = ("xla", "pallas")
 # percentile ladder of the latency-family drivers: median, p99, and the
 # serving-SLO tails (p999 / p9999)
 LATENCY_QS = (0.5, 0.99, 0.999, 0.9999)
+
+# counter of the slots a run entry stepped, per replica; counted only
+# where the number is already on the host (windowed programs from a fresh
+# state, and the warm + measure drivers), never through a new transfer:
+# barrier programs, bounded segments and run_completion go uncounted
+SLOTS_STEPPED = "engine.slots_stepped"
 
 
 @contextlib.contextmanager
@@ -748,214 +755,220 @@ class Simulator:
 
         qlen3 = st["qlen"].reshape(N, P, V)
         # ---- VC pre-arbitration: one candidate VC per (switch, in-port) ----
-        vc_rand = jax.random.uniform(k_vc, (N, P, V))
-        if pallas:
-            from ..kernels.switch_arb.ops import vc_prearb_op
-            vc_sel, has_pkt = vc_prearb_op(qlen3, vc_rand)
-        else:
-            vc_prio = jnp.where(qlen3 > 0, vc_rand, -1.0)
-            vc_sel = jnp.argmax(vc_prio, axis=2)                 # [N,P]
-            # the selected VC holds a packet iff any VC does
-            has_pkt = jnp.max(vc_prio, axis=2) >= 0.0
+        with jax.named_scope("vc_prearb"):
+            vc_rand = jax.random.uniform(k_vc, (N, P, V))
+            if pallas:
+                from ..kernels.switch_arb.ops import vc_prearb_op
+                vc_sel, has_pkt = vc_prearb_op(qlen3, vc_rand)
+            else:
+                vc_prio = jnp.where(qlen3 > 0, vc_rand, -1.0)
+                vc_sel = jnp.argmax(vc_prio, axis=2)                 # [N,P]
+                # the selected VC holds a packet iff any VC does
+                has_pkt = jnp.max(vc_prio, axis=2) >= 0.0
 
-        q_idx = (jnp.arange(N * P, dtype=jnp.int32).reshape(N, P) * V
-                 + vc_sel.astype(jnp.int32)).reshape(-1)           # [N*P]
-        head = st["qbuf"].reshape(-1)[q_idx * Q + st["qhead"][q_idx]]
-        net_pkt = jnp.where(has_pkt.reshape(-1), head, -1)
+        with jax.named_scope("route"):
+            q_idx = (jnp.arange(N * P, dtype=jnp.int32).reshape(N, P) * V
+                     + vc_sel.astype(jnp.int32)).reshape(-1)           # [N*P]
+            head = st["qbuf"].reshape(-1)[q_idx * Q + st["qhead"][q_idx]]
+            net_pkt = jnp.where(has_pkt.reshape(-1), head, -1)
 
-        # endpoint (NIC) heads — only in sub-round 0 (NIC link rate = 1/slot)
-        ep_head = st["eq_buf"].reshape(-1)[
-            jnp.arange(S, dtype=jnp.int32) * self.QE + st["eq_head"]]
-        ep_pkt = jnp.where((st["eq_len"] > 0) & ep_active, ep_head, -1)
+            # endpoint (NIC) heads — only in sub-round 0 (NIC link rate = 1/slot)
+            ep_head = st["eq_buf"].reshape(-1)[
+                jnp.arange(S, dtype=jnp.int32) * self.QE + st["eq_head"]]
+            ep_pkt = jnp.where((st["eq_len"] > 0) & ep_active, ep_head, -1)
 
-        # ---- unified requester table (static geometry from __init__) ----
-        cur = self.cur                                             # [NR]
-        pkt = jnp.concatenate([net_pkt, ep_pkt])
-        NR = self.NR
-        valid = pkt >= 0
-        pkt0 = jnp.maximum(pkt, 0)
+            # ---- unified requester table (static geometry from __init__) ----
+            cur = self.cur                                             # [NR]
+            pkt = jnp.concatenate([net_pkt, ep_pkt])
+            NR = self.NR
+            valid = pkt >= 0
+            pkt0 = jnp.maximum(pkt, 0)
 
-        bh = st["p_bh"][pkt0]
-        hops = bh & 0xFF
-        sd = st["p_sd"][pkt0]
-        t_lr = sd & 0xFFFF
-        # destination switch is a pure function of the destination leaf:
-        # a cache-resident [N1] gather, not another pool-wide attribute
-        eject = valid & (cur == self.leaf_ids[t_lr])
-        route = valid & ~eject
-        pol = self.cfg.policy
-        hf = self.has_failures
-        if hf:
-            # live tables from the state; live_row gates every policy's
-            # candidate set to live ports (dead switches contribute
-            # all-dead rows, so their packets freeze until drop/restore)
-            tmin = st["tbl_min"]
-            taway = st.get("tbl_away")
-            dflat = st["tbl_dist"]
-            live_row = st["link_up"].reshape(N, P)[cur]            # [NR,P]
-        else:
-            tmin = self.min_mask
-            taway = self.away_mask
-            dflat = self.dist.reshape(-1)
-            live_row = None
-        if pol == "polarized":
-            # full Polarized classification from toward/away bits alone:
-            # Forward = away-from-s & toward-t, Expansion = away & away
-            # (while d_cs < d_ct), Contraction = toward & toward (once
-            # d_cs >= d_ct); d(n,t) for the hop budget is d(c,t)+away-toward
-            s_lr = sd >> 16
-            dn_t = self._port_bits(tmin, t_lr, cur)
-            up_t = self._port_bits(taway, t_lr, cur)
-            dn_s = self._port_bits(tmin, s_lr, cur)
-            up_s = self._port_bits(taway, s_lr, cur)
-            d_ct = dflat[t_lr * N + cur]
-            d_cs = dflat[s_lr * N + cur]
-            src_side = (d_cs < d_ct)[:, None]
-            deroute = (up_s & up_t & src_side) | (dn_s & dn_t & ~src_side)
-            d_nt = (d_ct[:, None] + up_t.astype(jnp.int16)
-                    - dn_t.astype(jnp.int16))
-            budget_ok = (hops[:, None] + 1 + d_nt) <= self.cfg.max_hops
-            allowed = (up_s & dn_t) | (deroute & budget_ok)
-            next_vc = jnp.minimum(hops // 2, V - 1)
-        elif pol == "degraded":
-            # FatPaths-style layered recovery: minimal toward ports while
-            # any are live; when failures kill them all, fall back to live
-            # away ports (one layer up, +2 hops round trip) within the hop
-            # budget.  On a pristine fabric the fallback never fires, so
-            # degraded == minimal_adaptive bit for bit.
-            toward = self._port_bits(tmin, t_lr, cur)
-            away = self._port_bits(taway, t_lr, cur)
+            bh = st["p_bh"][pkt0]
+            hops = bh & 0xFF
+            sd = st["p_sd"][pkt0]
+            t_lr = sd & 0xFFFF
+            # destination switch is a pure function of the destination leaf:
+            # a cache-resident [N1] gather, not another pool-wide attribute
+            eject = valid & (cur == self.leaf_ids[t_lr])
+            route = valid & ~eject
+            pol = self.cfg.policy
+            hf = self.has_failures
             if hf:
-                toward = toward & live_row
-                away = away & live_row
-            d_ct = dflat[t_lr * N + cur]
-            no_min = ~jnp.any(toward, axis=1)
-            budget_ok = (hops[:, None] + 2 + d_ct[:, None]) <= self.cfg.max_hops
-            fallback = no_min[:, None] & away & budget_ok
-            deroute = fallback
-            allowed = toward | fallback
-            next_vc = jnp.minimum(hops // 2, V - 1)
-        elif pol in ("minimal_adaptive", "ksp"):
-            allowed = self._port_bits(tmin, t_lr, cur)
-            deroute = jnp.zeros_like(allowed)
-            next_vc = jnp.minimum(hops // 2, V - 1)
-        elif pol in ("ugal", "valiant"):
-            mid_lr = st["p_mid"][pkt0]
-            tgt = jnp.where(mid_lr >= 0, mid_lr, t_lr)
-            allowed = self._port_bits(tmin, tgt, cur)
-            deroute = jnp.zeros_like(allowed)
-            next_vc = jnp.minimum(hops, V - 1)
-        else:
-            raise ValueError(pol)
-        if hf and pol != "degraded":   # degraded gated its layers above
-            allowed = allowed & live_row
+                # live tables from the state; live_row gates every policy's
+                # candidate set to live ports (dead switches contribute
+                # all-dead rows, so their packets freeze until drop/restore)
+                tmin = st["tbl_min"]
+                taway = st.get("tbl_away")
+                dflat = st["tbl_dist"]
+                live_row = st["link_up"].reshape(N, P)[cur]            # [NR,P]
+            else:
+                tmin = self.min_mask
+                taway = self.away_mask
+                dflat = self.dist.reshape(-1)
+                live_row = None
+            if pol == "polarized":
+                # full Polarized classification from toward/away bits alone:
+                # Forward = away-from-s & toward-t, Expansion = away & away
+                # (while d_cs < d_ct), Contraction = toward & toward (once
+                # d_cs >= d_ct); d(n,t) for the hop budget is d(c,t)+away-toward
+                s_lr = sd >> 16
+                dn_t = self._port_bits(tmin, t_lr, cur)
+                up_t = self._port_bits(taway, t_lr, cur)
+                dn_s = self._port_bits(tmin, s_lr, cur)
+                up_s = self._port_bits(taway, s_lr, cur)
+                d_ct = dflat[t_lr * N + cur]
+                d_cs = dflat[s_lr * N + cur]
+                src_side = (d_cs < d_ct)[:, None]
+                deroute = (up_s & up_t & src_side) | (dn_s & dn_t & ~src_side)
+                d_nt = (d_ct[:, None] + up_t.astype(jnp.int16)
+                        - dn_t.astype(jnp.int16))
+                budget_ok = (hops[:, None] + 1 + d_nt) <= self.cfg.max_hops
+                allowed = (up_s & dn_t) | (deroute & budget_ok)
+                next_vc = jnp.minimum(hops // 2, V - 1)
+            elif pol == "degraded":
+                # FatPaths-style layered recovery: minimal toward ports while
+                # any are live; when failures kill them all, fall back to live
+                # away ports (one layer up, +2 hops round trip) within the hop
+                # budget.  On a pristine fabric the fallback never fires, so
+                # degraded == minimal_adaptive bit for bit.
+                toward = self._port_bits(tmin, t_lr, cur)
+                away = self._port_bits(taway, t_lr, cur)
+                if hf:
+                    toward = toward & live_row
+                    away = away & live_row
+                d_ct = dflat[t_lr * N + cur]
+                no_min = ~jnp.any(toward, axis=1)
+                budget_ok = (hops[:, None] + 2 + d_ct[:, None]) <= self.cfg.max_hops
+                fallback = no_min[:, None] & away & budget_ok
+                deroute = fallback
+                allowed = toward | fallback
+                next_vc = jnp.minimum(hops // 2, V - 1)
+            elif pol in ("minimal_adaptive", "ksp"):
+                allowed = self._port_bits(tmin, t_lr, cur)
+                deroute = jnp.zeros_like(allowed)
+                next_vc = jnp.minimum(hops // 2, V - 1)
+            elif pol in ("ugal", "valiant"):
+                mid_lr = st["p_mid"][pkt0]
+                tgt = jnp.where(mid_lr >= 0, mid_lr, t_lr)
+                allowed = self._port_bits(tmin, tgt, cur)
+                deroute = jnp.zeros_like(allowed)
+                next_vc = jnp.minimum(hops, V - 1)
+            else:
+                raise ValueError(pol)
+            if hf and pol != "degraded":   # degraded gated its layers above
+                allowed = allowed & live_row
 
-        # congestion signal: local output queue + downstream input queue for
-        # the flight VC.  Credit = room in the local output queue.  Both
-        # lookups are contiguous row gathers from the V-major layout
-        # (row = switch * V + flight VC), built once per round.
-        oq_v = st["oq_len"].reshape(N, P, V).transpose(0, 2, 1) \
-            .reshape(N * V, P)
-        qd_v = st["qlen"][self._dq_perm].reshape(N * V, P)
-        occ_row = cur * V + next_vc                                # [NR]
-        oq_occ = oq_v[occ_row]                                     # [NR,P]
-        occ = oq_occ + qd_v[occ_row]
-        credit = oq_occ < OQ
-        tie = jax.random.uniform(k_tie, (NR, P))
-        rnd = jax.random.randint(k_arb, (NR,), 0, 1 << 8, dtype=jnp.int32)
-        mask = allowed & credit
-        if pol == "ksp":        # random walk: score is the tiebreak alone
-            occ = jnp.zeros_like(occ)
-            deroute = jnp.zeros_like(deroute)
-        if pallas:
-            # fused score-evaluation + segmented output arbitration kernel
-            from ..kernels.switch_arb.ops import switch_arbitrate_flat
-            port, win, seg = switch_arbitrate_flat(
-                occ, deroute, mask, tie, route, rnd, self._lo,
-                penalty=float(self.cfg.deroute_penalty),
-                row_of=self._row_of, n_switches=N, r_max=self.R_max)
-        else:
-            score = (occ.astype(jnp.float32)
-                     + self.cfg.deroute_penalty * deroute + tie)
-            score = jnp.where(mask, score, BIG)
-            port = jnp.argmin(score, axis=1).astype(jnp.int32)
-            can_move = route & (jnp.min(score, axis=1) < BIG)
+            # congestion signal: local output queue + downstream input queue for
+            # the flight VC.  Credit = room in the local output queue.  Both
+            # lookups are contiguous row gathers from the V-major layout
+            # (row = switch * V + flight VC), built once per round.
+            oq_v = st["oq_len"].reshape(N, P, V).transpose(0, 2, 1) \
+                .reshape(N * V, P)
+            qd_v = st["qlen"][self._dq_perm].reshape(N * V, P)
+            occ_row = cur * V + next_vc                                # [NR]
+            oq_occ = oq_v[occ_row]                                     # [NR,P]
+            occ = oq_occ + qd_v[occ_row]
+            credit = oq_occ < OQ
+            mask = allowed & credit
+            if pol == "ksp":        # random walk: score is the tiebreak alone
+                occ = jnp.zeros_like(occ)
+                deroute = jnp.zeros_like(deroute)
 
-            # ---- output arbitration: one grant per (switch, out-port) ----
-            out_key = cur * P + port                               # [NR]
-            # unique int32 priorities: 8 random high bits | requester index
-            prio = (rnd << 23) | self._lo
-            prio = jnp.where(can_move, prio, -1)
-            # dense per-switch segmented max — the same scatter-free
-            # reduction the Pallas kernel runs (static row gathers; rows
-            # with no requester carry priority -1)
-            prio_d = jnp.where(self._dense_valid,
-                               prio[self._dense_src].reshape(N, self.R_max),
-                               -1)
-            port_d = port[self._dense_src].reshape(N, self.R_max)
-            hot = ((port_d[:, :, None]
-                    == jnp.arange(P, dtype=jnp.int32))
-                   & (prio_d >= 0)[:, :, None])                    # [N,R,P]
-            seg = jnp.max(jnp.where(hot, prio_d[:, :, None], -1),
-                          axis=1).reshape(-1)                      # [N*P]
-            win = can_move & (seg[out_key] == prio)
+        with jax.named_scope("out_arb"):
+            tie = jax.random.uniform(k_tie, (NR, P))
+            rnd = jax.random.randint(k_arb, (NR,), 0, 1 << 8, dtype=jnp.int32)
+            if pallas:
+                # fused score-evaluation + segmented output arbitration kernel
+                from ..kernels.switch_arb.ops import switch_arbitrate_flat
+                port, win, seg = switch_arbitrate_flat(
+                    occ, deroute, mask, tie, route, rnd, self._lo,
+                    penalty=float(self.cfg.deroute_penalty),
+                    row_of=self._row_of, n_switches=N, r_max=self.R_max)
+            else:
+                score = (occ.astype(jnp.float32)
+                         + self.cfg.deroute_penalty * deroute + tie)
+                score = jnp.where(mask, score, BIG)
+                port = jnp.argmin(score, axis=1).astype(jnp.int32)
+                can_move = route & (jnp.min(score, axis=1) < BIG)
+
+                # ---- output arbitration: one grant per (switch, out-port) ----
+                out_key = cur * P + port                               # [NR]
+                # unique int32 priorities: 8 random high bits | requester index
+                prio = (rnd << 23) | self._lo
+                prio = jnp.where(can_move, prio, -1)
+                # dense per-switch segmented max — the same scatter-free
+                # reduction the Pallas kernel runs (static row gathers; rows
+                # with no requester carry priority -1)
+                prio_d = jnp.where(self._dense_valid,
+                                   prio[self._dense_src].reshape(N, self.R_max),
+                                   -1)
+                port_d = port[self._dense_src].reshape(N, self.R_max)
+                hot = ((port_d[:, :, None]
+                        == jnp.arange(P, dtype=jnp.int32))
+                       & (prio_d >= 0)[:, :, None])                    # [N,R,P]
+                seg = jnp.max(jnp.where(hot, prio_d[:, :, None], -1),
+                              axis=1).reshape(-1)                      # [N*P]
+                win = can_move & (seg[out_key] == prio)
 
         # ---- moves: input queue -> output queue ----
-        # XLA CPU scatters serialize element by element, so the queue
-        # updates are phrased as gathers + dense one-hot selects instead:
-        # the winning priority word per output port *is* the inverted grant
-        # (its low 23 bits are the unique flat requester index).
-        exist = seg >= 0                                           # [N*P]
-        wlo = jnp.where(exist, seg & ((1 << 23) - 1), 0)
-        win_pkt = pkt0[wlo]                                        # [N*P]
-        win_vc = next_vc[wlo]
-        v_ids = jnp.arange(V, dtype=jnp.int32)
-        push = (exist[:, None] & (win_vc[:, None] == v_ids)).reshape(-1)
-        pos = (st["oq_head"] + st["oq_len"]) % OQ                  # [NQ]
-        slot_hot = push[:, None] & (jnp.arange(OQ, dtype=jnp.int32)[None, :]
-                                    == pos[:, None])
-        win_pkt_q = jnp.broadcast_to(win_pkt[:, None],
-                                     (N * P, V)).reshape(-1)       # [NQ]
-        oq_buf = jnp.where(slot_hot, win_pkt_q[:, None], st["oq_buf"])
-        oq_len = st["oq_len"] + push.astype(jnp.int32)
+        with jax.named_scope("moves"):
+            # XLA CPU scatters serialize element by element, so the queue
+            # updates are phrased as gathers + dense one-hot selects instead:
+            # the winning priority word per output port *is* the inverted grant
+            # (its low 23 bits are the unique flat requester index).
+            exist = seg >= 0                                           # [N*P]
+            wlo = jnp.where(exist, seg & ((1 << 23) - 1), 0)
+            win_pkt = pkt0[wlo]                                        # [N*P]
+            win_vc = next_vc[wlo]
+            v_ids = jnp.arange(V, dtype=jnp.int32)
+            push = (exist[:, None] & (win_vc[:, None] == v_ids)).reshape(-1)
+            pos = (st["oq_head"] + st["oq_len"]) % OQ                  # [NQ]
+            slot_hot = push[:, None] & (jnp.arange(OQ, dtype=jnp.int32)[None, :]
+                                        == pos[:, None])
+            win_pkt_q = jnp.broadcast_to(win_pkt[:, None],
+                                         (N * P, V)).reshape(-1)       # [NQ]
+            oq_buf = jnp.where(slot_hot, win_pkt_q[:, None], st["oq_buf"])
+            oq_len = st["oq_len"] + push.astype(jnp.int32)
 
-        # pops: winners + ejectors leave their input queues (each
-        # (switch, in-port) pops at most its one pre-arbitrated VC — dense)
-        leave = win | eject
-        net_leave = leave[: N * P]
-        pop = (net_leave[:, None]
-               & (vc_sel.reshape(-1).astype(jnp.int32)[:, None] == v_ids)
-               ).reshape(-1).astype(jnp.int32)                     # [NQ]
-        qhead = (st["qhead"] + pop) % Q
-        qlen = st["qlen"] - pop
-        ep_leave = leave[N * P:]
-        eq_head = (st["eq_head"] + ep_leave.astype(jnp.int32)) % self.QE
-        eq_len = st["eq_len"] - ep_leave.astype(jnp.int32)
+            # pops: winners + ejectors leave their input queues (each
+            # (switch, in-port) pops at most its one pre-arbitrated VC — dense)
+            leave = win | eject
+            net_leave = leave[: N * P]
+            pop = (net_leave[:, None]
+                   & (vc_sel.reshape(-1).astype(jnp.int32)[:, None] == v_ids)
+                   ).reshape(-1).astype(jnp.int32)                     # [NQ]
+            qhead = (st["qhead"] + pop) % Q
+            qlen = st["qlen"] - pop
+            ep_leave = leave[N * P:]
+            eq_head = (st["eq_head"] + ep_leave.astype(jnp.int32)) % self.QE
+            eq_len = st["eq_len"] - ep_leave.astype(jnp.int32)
 
-        # ejections: free pool (O(N*P) free-list push), record stats.  Only
-        # network input ports can eject (same-leaf traffic never enters the
-        # network), so the pool scatters index the net rows alone.
-        ej_n = eject[: N * P]
-        pkt_n = pkt0[: N * P]
-        erank = jnp.cumsum(ej_n.astype(jnp.int32)) - 1
-        fpos = (st["fl_head"] + st["fl_len"] + jnp.maximum(erank, 0)) % self.pool
-        fl_buf = st["fl_buf"].at[jnp.where(ej_n, fpos, self.pool)].set(
-            pkt_n, mode="drop")
-        fl_len = st["fl_len"] + ej_n.sum(dtype=jnp.int32)
-        lat = jnp.clip(st["slot"] - (bh[: N * P] >> 8) + 1, 0,
-                       self.cfg.hist_bins - 1)
-        lat_hist = st["lat_hist"].at[jnp.where(ej_n, lat, 0)].add(
-            jnp.where(ej_n, 1, 0))
+            # ejections: free pool (O(N*P) free-list push), record stats.  Only
+            # network input ports can eject (same-leaf traffic never enters the
+            # network), so the pool scatters index the net rows alone.
+            ej_n = eject[: N * P]
+            pkt_n = pkt0[: N * P]
+            erank = jnp.cumsum(ej_n.astype(jnp.int32)) - 1
+            fpos = (st["fl_head"] + st["fl_len"] + jnp.maximum(erank, 0)) % self.pool
+            fl_buf = st["fl_buf"].at[jnp.where(ej_n, fpos, self.pool)].set(
+                pkt_n, mode="drop")
+            fl_len = st["fl_len"] + ej_n.sum(dtype=jnp.int32)
+            lat = jnp.clip(st["slot"] - (bh[: N * P] >> 8) + 1, 0,
+                           self.cfg.hist_bins - 1)
+            lat_hist = st["lat_hist"].at[jnp.where(ej_n, lat, 0)].add(
+                jnp.where(ej_n, 1, 0))
 
-        st = dict(st)
-        st["oq_buf"] = oq_buf.reshape(self.NQ, OQ)
-        st["oq_len"] = oq_len
-        st["qhead"], st["qlen"] = qhead, qlen
-        st["eq_head"], st["eq_len"] = eq_head, eq_len
-        st["fl_buf"], st["fl_len"] = fl_buf, fl_len
-        st["lat_hist"] = lat_hist
-        st["ejected"] = st["ejected"] + eject.sum(dtype=jnp.int32)
-        st["hop_sum"] = st["hop_sum"] + jnp.where(eject, hops, 0).sum(dtype=jnp.int32)
+            st = dict(st)
+            st["oq_buf"] = oq_buf.reshape(self.NQ, OQ)
+            st["oq_len"] = oq_len
+            st["qhead"], st["qlen"] = qhead, qlen
+            st["eq_head"], st["eq_len"] = eq_head, eq_len
+            st["fl_buf"], st["fl_len"] = fl_buf, fl_len
+            st["lat_hist"] = lat_hist
+            st["ejected"] = st["ejected"] + eject.sum(dtype=jnp.int32)
+            st["hop_sum"] = st["hop_sum"] + jnp.where(eject, hops, 0).sum(
+                dtype=jnp.int32)
         return st
 
     def _link_phase(self, st, key):
@@ -1034,13 +1047,18 @@ class Simulator:
             st["key"], 3 + self.cfg.speedup)
         st = dict(st)
         st["key"] = key
-        st = self._inject(st, k_inj, traffic)
+        # phase names are HLO metadata (op_name), read by the benchmark's
+        # per-phase device time; they change no computation
+        with jax.named_scope("inject"):
+            st = self._inject(st, k_inj, traffic)
         for r in range(self.cfg.speedup):
             st = self._crossbar_round(st, k_xb[r], ep_active=True)
-        st = self._link_phase(st, k_link)
+        with jax.named_scope("link"):
+            st = self._link_phase(st, k_link)
         st["slot"] = st["slot"] + 1
         if traffic.pattern == "program":
-            st = self._advance_program(st, traffic, chunk, max_slots)
+            with jax.named_scope("program"):
+                st = self._advance_program(st, traffic, chunk, max_slots)
         return st
 
     # ------------------------------------------------------------------ #
@@ -1469,10 +1487,12 @@ class Simulator:
 
     def run_throughput(self, traffic: Traffic, warm: int = 200,
                        measure: int = 400, seed: int = 0) -> dict:
-        st = self.make_state(traffic, seed)
+        with tracing.span("runner.prepare"):
+            st = self.make_state(traffic, seed)
         st = self.run_chunk(st, traffic, warm)
         base = self._counter_snapshot(st)
         st = self.run_chunk(st, traffic, measure)
+        tracing.count(SLOTS_STEPPED, warm + measure)
         # warm/measure deltas computed on device, fetched in ONE transfer
         # (the old path issued three blocking int() syncs per phase)
         m = jax.device_get({k: st[k] - base[k] for k in base}
@@ -1502,10 +1522,12 @@ class Simulator:
                                                         sharder)
         else:
             chunk = lambda s, n: self.run_chunk_batch(s, traffic, n)
-        st = self.make_batch_state(traffic, seeds)
+        with tracing.span("runner.prepare"):
+            st = self.make_batch_state(traffic, seeds)
         st = chunk(st, warm)
         base = self._counter_snapshot(st)
         st = chunk(st, measure)
+        tracing.count(SLOTS_STEPPED, (warm + measure) * len(seeds))
         m = jax.device_get({k: st[k] - base[k] for k in base}
                            | {"ejected_total": st["ejected"]})
         e, h = np.asarray(m["ejected"]), np.asarray(m["hop_sum"])
@@ -1519,10 +1541,12 @@ class Simulator:
 
     def run_latency(self, traffic: Traffic, warm: int = 200,
                     measure: int = 600, seed: int = 0) -> dict:
-        st = self.make_state(traffic, seed)
+        with tracing.span("runner.prepare"):
+            st = self.make_state(traffic, seed)
         st = self.run_chunk(st, traffic, warm)
         base = st["lat_hist"] + 0            # fresh buffer; st is donated
         st = self.run_chunk(st, traffic, measure)
+        tracing.count(SLOTS_STEPPED, warm + measure)
         hist = np.asarray(jax.device_get(st["lat_hist"] - base))
         return {"hist": hist, **percentiles(hist, LATENCY_QS)}
 
@@ -1531,10 +1555,12 @@ class Simulator:
         """Batched ``run_latency``: per-replica histograms and percentile
         lists (``{"p0.5": [R floats], ...}``; NaN where a replica ejected
         nothing in the window)."""
-        st = self.make_batch_state(traffic, seeds)
+        with tracing.span("runner.prepare"):
+            st = self.make_batch_state(traffic, seeds)
         st = self.run_chunk_batch(st, traffic, warm)
         base = st["lat_hist"] + 0
         st = self.run_chunk_batch(st, traffic, measure)
+        tracing.count(SLOTS_STEPPED, (warm + measure) * len(seeds))
         hist = np.asarray(jax.device_get(st["lat_hist"] - base))  # [R, bins]
         per = [percentiles(row, LATENCY_QS) for row in hist]
         out = {"hist": hist}
@@ -1590,10 +1616,12 @@ class Simulator:
         if traffic.pattern != "arrival":
             raise ValueError(f"run_serving needs Traffic('arrival'), got "
                              f"{traffic.pattern!r}")
-        st = self.make_state(traffic, seed)
+        with tracing.span("runner.prepare"):
+            st = self.make_state(traffic, seed)
         st = self.run_chunk(st, traffic, warm)
         base = self._serving_snapshot(st)
         st = self.run_chunk(st, traffic, measure)
+        tracing.count(SLOTS_STEPPED, warm + measure)
         m = jax.device_get({k: st[k] - base[k] for k in base})
         return {**self._serving_metrics(m, self.S, measure), "state": st}
 
@@ -1604,10 +1632,12 @@ class Simulator:
         if traffic.pattern != "arrival":
             raise ValueError(f"run_serving needs Traffic('arrival'), got "
                              f"{traffic.pattern!r}")
-        st = self.make_batch_state(traffic, seeds)
+        with tracing.span("runner.prepare"):
+            st = self.make_batch_state(traffic, seeds)
         st = self.run_chunk_batch(st, traffic, warm)
         base = self._serving_snapshot(st)
         st = self.run_chunk_batch(st, traffic, measure)
+        tracing.count(SLOTS_STEPPED, (warm + measure) * len(seeds))
         m = jax.device_get({k: st[k] - base[k] for k in base})
         return {**self._serving_metrics(m, self.S, measure), "state": st}
 
@@ -1723,7 +1753,8 @@ class Simulator:
         sched = self.failures
         drop = sched.policy == "drop"
         trans = sched.transitions()
-        st = self.make_state(traffic, seed)
+        with tracing.span("runner.prepare"):
+            st = self.make_state(traffic, seed)
         now = 0
         ti = 0
         active: list = []
@@ -1762,6 +1793,7 @@ class Simulator:
                                            "lat_hist")}
             st = apply_due(st, warm + measure)
             st = advance_to(st, warm + measure)
+            tracing.count(SLOTS_STEPPED, warm + measure)
             m = jax.device_get({k: st[k] - base[k] for k in base}
                                | {"ejected_total": st["ejected"]})
         finally:
@@ -1806,7 +1838,10 @@ class Simulator:
         next segment alongside ``state``); a chain of bounded segments is
         bitwise-identical to one unbounded call.
         """
-        st = state if state is not None else self.make_state(traffic, seed)
+        if state is None:
+            with tracing.span("runner.prepare"):
+                state = self.make_state(traffic, seed)
+        st = state
         # p_bh packs the born slot above the hop byte; past 2^23 slots the
         # shifted value would wrap int32 and corrupt latency measurement
         assert max_slots < (1 << 23), \
@@ -1841,9 +1876,11 @@ class Simulator:
                              chunk: int = 128,
                              max_slots: int = 100_000) -> dict:
         """Batched ``run_completion`` over fresh per-seed replica states."""
+        with tracing.span("runner.prepare"):
+            state = self.make_batch_state(traffic, seeds)
         return self.run_completion(
             traffic, expected, chunk=chunk, max_slots=max_slots,
-            state=self.make_batch_state(traffic, seeds))
+            state=state)
 
     # ------------------------------------------------------------------ #
     # compiled workload programs (repro.workloads)
@@ -2025,12 +2062,14 @@ class Simulator:
         assert max_slots < (1 << 23), \
             "max_slots overflows the p_bh born-slot packing (< 2^23)"
         traffic = self.program_traffic(program)
-        if state is not None:
-            st = state
-        elif seeds is not None:
-            st = self.make_program_batch_state(program, seeds)
+        fresh = state is None
+        if fresh:
+            with tracing.span("runner.prepare"):
+                st = (self.make_program_batch_state(program, seeds)
+                      if seeds is not None
+                      else self.make_program_state(program, seed))
         else:
-            st = self.make_program_state(program, seed)
+            st = state
         st = {k: jnp.asarray(v) for k, v in st.items()}
         with _quiet_cpu_donation():
             if budget_chunks is None:
@@ -2047,6 +2086,10 @@ class Simulator:
             final = np.asarray(st["slot"])[..., None]
             done = np.where(done >= 0, done, final)
             slots = done[..., -1]
+            if fresh and budget_chunks is None:
+                # whole chunks from slot 0: the final slot is what was
+                # stepped, per replica
+                tracing.count(SLOTS_STEPPED, int(final.sum()))
         else:
             slots = done.sum(axis=-1)
         completed = ok.all(axis=-1)
