@@ -327,7 +327,9 @@ def _cmd_estimate(args) -> int:
         over = (ram is not None and predicted > ram)
         print(f"{e.label()}  S={dims['n_endpoints']}  "
               f"masks={est['tables']['mask_layout']}  "
-              f"tables={format_bytes(est['tables']['device_mask_bytes'] + est['tables']['dist_leaf_bytes'])}  "
+              f"tables={format_bytes(est['tables']['device_table_bytes'])} "
+              f"device + {format_bytes(est['tables']['dist_leaf_bytes'])} "
+              "host  "
               f"state/replica={format_bytes(est['state_bytes_per_replica'])}  "
               f"total={format_bytes(est['total_bytes'])}  "
               f"peak={format_bytes(est['peak_bytes'])}  "

@@ -23,7 +23,7 @@ from __future__ import annotations
 from typing import Union
 
 from ..core import routing as _routing
-from ..core.routing import mask_table_bytes
+from ..core.routing import FUSED_POLICIES, mask_table_bytes, route_row_words
 from .registry import build_network
 from .specs import Experiment, NetworkSpec, RouteSpec
 
@@ -72,10 +72,15 @@ def estimate_memory(network: Union[NetworkSpec, Experiment],
     # the engine's pool default (SimConfig.pool or auto)
     pool = route.pool or int(min(2_000_000, max(1 << 14, s * 6)))
 
-    # ---- routing tables (device-resident) ---------------------------- #
+    # ---- routing tables ----------------------------------------------- #
     one_mask = mask_table_bytes(n1, n, p)
-    n_masks = 2 if route.policy in ("polarized", "degraded") else 1
     dist_bytes = n1 * n * 2                           # int16
+    # device tables the step reads: one fused route-row table (toward +
+    # away bits + int16 distance a row) for polarized/degraded; the
+    # toward-bit table and the int16 distance table otherwise
+    device_table_bytes = (n1 * n * route_row_words(p) * 4
+                          if route.policy in FUSED_POLICIES
+                          else one_mask + dist_bytes)
     # read the limit off the module so it tracks build_tables' "auto"
     # resolution exactly (including test-time overrides)
     mask_layout = ("dense" if one_mask <= _routing.DENSE_MASK_LIMIT
@@ -84,8 +89,8 @@ def estimate_memory(network: Union[NetworkSpec, Experiment],
     # regardless of policy); blocked streams them and retains nothing
     host_mask_bytes = 2 * one_mask if mask_layout == "dense" else 0
     tables = {
-        "dist_leaf_bytes": dist_bytes,
-        "device_mask_bytes": n_masks * one_mask,
+        "dist_leaf_bytes": dist_bytes,                # host dist_leaf
+        "device_table_bytes": device_table_bytes,
         "host_mask_bytes": host_mask_bytes,
         "mask_layout": mask_layout,
     }
@@ -112,13 +117,13 @@ def estimate_memory(network: Union[NetworkSpec, Experiment],
 
     # ---- failure-schedule state (per replica, armed schedules only) --- #
     # with a non-empty FailureSchedule the engine moves the routing
-    # tables INTO the state (tbl_min[/tbl_away] + tbl_dist) so
+    # tables INTO the state (tbl_rows, or tbl_min + tbl_dist) so
     # update_tables can rewrite them without recompiling, and adds the
     # live up-masks (link_up [N*P] bool, switch_up [N] bool) plus the
     # fail_drop counter
     has_failures = (network.failures is not None
                     and len(network.failures) > 0)
-    failure_state = (n_masks * one_mask + dist_bytes   # tbl_min/away/dist
+    failure_state = (device_table_bytes                # tbl_rows | min+dist
                      + n * p + n                       # link_up, switch_up
                      + 4) if has_failures else 0       # fail_drop
     state += failure_state
@@ -134,7 +139,7 @@ def estimate_memory(network: Union[NetworkSpec, Experiment],
         # at once while repacking)
         transient += 2 * min(256, n1) * n * w * 4
 
-    total = (tables["dist_leaf_bytes"] + tables["device_mask_bytes"]
+    total = (tables["dist_leaf_bytes"] + tables["device_table_bytes"]
              + tables["host_mask_bytes"] + constants + replicas * state)
     return {
         "network": network.to_dict(),

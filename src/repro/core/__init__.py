@@ -5,9 +5,9 @@ from .topology import (
 )
 from .routing import (
     bfs_distances, RoutingTables, TableDelta, build_tables, pack_port_masks,
-    iter_port_mask_blocks, mask_table_bytes, polarized_port_mask,
-    route_packet_host, find_corners, POLICIES, MASK_LAYOUTS,
-    DENSE_MASK_LIMIT, UNREACHABLE,
+    iter_port_mask_blocks, mask_table_bytes, route_row_words, pack_route_rows,
+    polarized_port_mask, route_packet_host, find_corners, POLICIES,
+    FUSED_POLICIES, MASK_LAYOUTS, DENSE_MASK_LIMIT, UNREACHABLE,
 )
 from .failures import FailureEvent, FailureSchedule, canonical_link_ids
 from .analytics import (

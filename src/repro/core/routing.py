@@ -29,9 +29,12 @@ __all__ = [
     "pack_port_masks",
     "iter_port_mask_blocks",
     "mask_table_bytes",
+    "route_row_words",
+    "pack_route_rows",
     "polarized_port_mask",
     "route_packet_host",
     "POLICIES",
+    "FUSED_POLICIES",
     "MASK_LAYOUTS",
     "DENSE_MASK_LIMIT",
     "UNREACHABLE",
@@ -39,6 +42,11 @@ __all__ = [
 
 POLICIES = ("polarized", "minimal_adaptive", "ksp", "ugal", "valiant",
             "degraded")
+
+# policies that read away bits and distances: the simulator keeps one
+# fused route-row table for them (:func:`pack_route_rows`) in place of the
+# toward-bit and distance tables of the minimal policies
+FUSED_POLICIES = ("polarized", "degraded")
 
 MASK_LAYOUTS = ("auto", "dense", "blocked")
 
@@ -414,6 +422,50 @@ def pack_port_masks(dist_leaf: np.ndarray, nbrs: np.ndarray,
 def mask_table_bytes(n1: int, n: int, p: int) -> int:
     """Bytes of ONE dense ``[N1, N, W]`` uint32 mask table."""
     return n1 * n * ((p + 31) // 32) * 4
+
+
+# ---------------------------------------------------------------------- #
+# fused route rows: toward bits, away bits and distance in one row
+# ---------------------------------------------------------------------- #
+def route_row_words(p: int) -> int:
+    """uint32 words of one fused route row for ``p`` ports.
+
+    Bit layout of the row read as one little-endian bit string: toward
+    bit of port ``j`` at bit ``j``, away bit of port ``j`` at bit
+    ``p + j``, and the int16 distance in the top 16 bits of the last word.
+    At radix 36 (``p = 36``) that is 88 bits in 3 words.
+    """
+    return (2 * p + 16 + 31) // 32
+
+
+def _place_bits(out: np.ndarray, words: np.ndarray, offset: int) -> None:
+    """OR the bit string ``words[..., :]`` into ``out`` at bit ``offset``.
+    Bits of ``words`` past the field are zero, so nothing spills."""
+    k = out.shape[-1]
+    for j in range(words.shape[-1]):
+        q, s = divmod(offset + 32 * j, 32)
+        w = words[..., j]
+        out[..., q] |= w << np.uint32(s)
+        if s and q + 1 < k:
+            out[..., q + 1] |= w >> np.uint32(32 - s)
+
+
+def pack_route_rows(min_words: np.ndarray, away_words: np.ndarray,
+                    dist: np.ndarray, p: int) -> np.ndarray:
+    """``[..., K]`` uint32 fused route rows (:func:`route_row_words`).
+
+    ``min_words``/``away_words`` are ``[..., W]`` toward/away bit words of
+    the same rows (:func:`_pack_mask_block`), ``dist`` the ``[...]`` int16
+    distances, exact (:data:`UNREACHABLE` included).  The one packing used
+    by the simulator's table build and by its failure-delta updates.
+    """
+    k = route_row_words(p)
+    out = np.zeros(dist.shape + (k,), np.uint32)
+    _place_bits(out, min_words.astype(np.uint32, copy=False), 0)
+    _place_bits(out, away_words.astype(np.uint32, copy=False), p)
+    d16 = np.asarray(dist, np.int16).view(np.uint16).astype(np.uint32)
+    out[..., k - 1] |= d16 << np.uint32(16)
+    return out
 
 
 def build_tables(topo: Topology, full: bool = False, *,

@@ -22,6 +22,9 @@ Names::
     topology.build        registry.build_network
     routing.tables        core.routing.build_tables
     engine.slots_stepped  counter: slots a run entry stepped, per replica
+    engine.route_rows     counter: routing-table rows the route phase gathered
+                          in those slots (slots x speedup x requesters x rows
+                          per requester: 2 polarized, 1 otherwise)
 """
 from __future__ import annotations
 

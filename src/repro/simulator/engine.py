@@ -14,8 +14,8 @@ Routing is evaluated *inside* the jitted step on compact precomputed tables:
 
 * ``polarized``        — the paper's adapted Polarized routing (Section 4.3.2)
   with VC = updown-phase = hops // 2 (1 VC per Up-Down pass — the halved
-  deadlock resources of Section 4.3).  Consumes two int16 distance rows
-  (to source and to target) per requester.
+  deadlock resources of Section 4.3).  Gathers two fused route rows (to
+  source and to target) per requester.
 * ``minimal_adaptive`` — adaptive minimal (Fat-Tree / OFT "MIN").
 * ``ksp``              — randomized minimal-DAG walk (models KSP's random
   choice among precomputed shortest paths).
@@ -25,7 +25,11 @@ Routing is evaluated *inside* the jitted step on compact precomputed tables:
 The minimal policies never gather ``[P]``-wide distance rows: the candidate
 port set for (switch, target leaf) is static, so ``build_tables`` packs it
 into uint32 bitmasks (``RoutingTables.min_mask``) and the step does one
-word gather plus a bit test per requester.
+word gather plus a bit test per requester.  ``polarized`` and ``degraded``
+also read away bits and distances; they keep one fused table instead
+(``route_rows``: toward bits, away bits and the int16 distance of a
+(leaf, switch) pair in one row, :func:`repro.core.routing.pack_route_rows`),
+so a requester gathers one row per leaf it classifies against.
 
 The step is engineered to be compute-bound, not gather/scatter-bound:
 
@@ -76,7 +80,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.routing import RoutingTables
+from ..core.routing import (FUSED_POLICIES, RoutingTables, pack_route_rows,
+                            route_row_words)
 from ..runtime import tracing
 from ..workloads.patterns import (ARRIVAL_PATTERNS, BERNOULLI_PATTERNS,
                                   bounded_pareto_mean, check_arrival,
@@ -95,6 +100,9 @@ LATENCY_QS = (0.5, 0.99, 0.999, 0.9999)
 # state, and the warm + measure drivers), never through a new transfer:
 # barrier programs, bounded segments and run_completion go uncounted
 SLOTS_STEPPED = "engine.slots_stepped"
+# beside it: routing-table rows the route phase gathered in those slots
+# (slots x speedup x requesters x rows per requester), from static numbers
+ROUTE_ROWS = "engine.route_rows"
 
 
 @contextlib.contextmanager
@@ -232,9 +240,10 @@ class Simulator:
         # empty one) every step traces exactly as before — routing tables
         # are read-only arguments of the jitted loops and no live masks
         # ride in the state, so the parity goldens are bitwise-untouched.
-        # With a schedule, the tables move into the state (``tbl_min`` /
-        # ``tbl_away`` / ``tbl_dist`` + ``link_up`` / ``switch_up``) so
-        # ``update_tables`` can rewrite them mid-run without recompiling.
+        # With a schedule, the tables move into the state (``tbl_rows``
+        # for the fused policies, ``tbl_min`` / ``tbl_dist`` otherwise,
+        # + ``link_up`` / ``switch_up``) so ``update_tables`` can rewrite
+        # them mid-run without recompiling.
         self.failures = failures
         self.has_failures = failures is not None and len(failures.events) > 0
         if failures is not None:
@@ -255,19 +264,27 @@ class Simulator:
         self.valid_port = self.nbrs >= 0
         self.nbrs0 = jnp.maximum(self.nbrs, 0)
         assert (tables.dist_leaf >= 0).all(), "disconnected topology"
-        # int16 distance table: the rows Polarized gathers per sub-round are
-        # half the width of the old int32 table; all consumers use the
-        # values in comparisons / tiny products, where int16 is exact.
-        self.dist = jnp.asarray(tables.dist_leaf, jnp.int16)     # [N1,N]
         self.leaf_ids = jnp.asarray(topo.leaf_ids, jnp.int32)    # [N1]
-        # compact port bitmasks [N1*N, W]: one uint32-word gather + bit
-        # test replaces a [P]-wide distance-row gather per requester
-        # (toward-bits drive the minimal policies; toward+away together
-        # encode the full Polarized classification).  Built by streaming
-        # leaf blocks — with blocked tables the dense numpy arrays are
-        # never materialized on the host.
         self.W = (self.P + 31) // 32
-        self.min_mask, self.away_mask = self._build_device_masks(tables)
+        self.fused = cfg.policy in FUSED_POLICIES
+        if self.fused:
+            # fused route rows [N1*N, K]: toward bits, away bits and the
+            # int16 distance of one (leaf, switch) pair, so the full
+            # Polarized classification against one leaf is one row gather
+            self.K = route_row_words(self.P)
+            self.route_rows = self._build_route_rows(tables)
+            self.min_mask = self.dist = None
+        else:
+            # int16 distance table (UGAL's inject reads it at mixed
+            # indices); all consumers use the values in comparisons / tiny
+            # products, where int16 is exact
+            self.dist = jnp.asarray(tables.dist_leaf, jnp.int16)  # [N1,N]
+            # compact port bitmasks [N1*N, W]: one uint32-word gather + bit
+            # test replaces a [P]-wide distance-row gather per requester.
+            # Built by streaming leaf blocks — with blocked tables the
+            # dense numpy arrays are never materialized on the host.
+            self.min_mask = self._build_device_masks(tables)
+            self.route_rows = None
         self._w_idx = jnp.asarray(np.arange(self.P) // 32, np.int32)
         self._b_idx = jnp.asarray(np.arange(self.P) % 32, np.uint32)
 
@@ -288,12 +305,12 @@ class Simulator:
         self._sharded_cache: dict = {}
         self._closed = False
 
-    # The routing tables are the largest device arrays (~0.9 GB at 104,976
+    # The routing tables are the largest device arrays (~0.8 GB at 104,976
     # endpoints).  The jitted loops take them as (undonated) arguments and
     # trace the step on a copy of the simulator bound to those arguments,
     # so they never become constants of the compiled program, which would
     # carry them inside every executable and persistent-cache entry.
-    _TABLE_ATTRS = ("min_mask", "away_mask", "dist")
+    _TABLE_ATTRS = ("min_mask", "dist", "route_rows")
 
     def _tables(self) -> dict:
         return {k: getattr(self, k) for k in self._TABLE_ATTRS}
@@ -305,33 +322,34 @@ class Simulator:
         return sim
 
     def _build_device_masks(self, tables: RoutingTables):
-        """Device mask tables ``[N1*N, W]``, assembled from streamed leaf
-        blocks (:meth:`RoutingTables.mask_blocks`).
+        """Device toward-bit table ``[N1*N, W]`` of the minimal policies,
+        assembled from streamed leaf blocks
+        (:meth:`RoutingTables.mask_blocks`).
 
         Works for both table layouts.  With ``mask_layout="blocked"`` the
         dense numpy arrays are never built: numpy peak is one
         ``[leaf_block, N, W]`` pair, and *retained* memory is the device
-        tables alone.  The assembly itself still peaks at ~2x one
-        policy's tables while ``jnp.concatenate`` copies the collected
-        blocks into the flat arrays (buffer donation is a no-op on the
-        CPU backends this targets, so a true in-place stream is not
-        available) — the blocked layout's durable win is retention, not
-        the assembly transient.  Only Polarized keeps the away bits — the
-        minimal policies never read them, and a second [N1*N, W] device
-        table is 100s of MB at paper scale.
+        table alone.  The assembly itself still peaks at ~2x the table
+        while ``jnp.concatenate`` copies the collected blocks into the
+        flat array — the blocked layout's durable win is retention, not
+        the assembly transient.
         """
-        need_away = self.cfg.policy in ("polarized", "degraded")
-        mins, aways = [], []
-        for _lo, _hi, min_b, away_b in tables.mask_blocks():
+        mins = []
+        for _lo, _hi, min_b, _away_b in tables.mask_blocks():
             mins.append(jnp.asarray(min_b.reshape(-1, self.W)))
-            if need_away:
-                aways.append(jnp.asarray(away_b.reshape(-1, self.W)))
-            del min_b, away_b
-        min_mask = mins[0] if len(mins) == 1 else jnp.concatenate(mins)
-        away_mask = None
-        if need_away:
-            away_mask = aways[0] if len(aways) == 1 else jnp.concatenate(aways)
-        return min_mask, away_mask
+        return mins[0] if len(mins) == 1 else jnp.concatenate(mins)
+
+    def _build_route_rows(self, tables: RoutingTables):
+        """Device fused route-row table ``[N1*N, K]``: streamed leaf blocks
+        packed into one host array, which moves to the device once — the
+        device never holds two copies of it."""
+        n, k = self.N, self.K
+        rows = np.empty((self.n1 * n, k), np.uint32)
+        for lo, hi, min_b, away_b in tables.mask_blocks():
+            rows[lo * n:hi * n] = pack_route_rows(
+                min_b, away_b, tables.dist_leaf[lo:hi], self.P
+            ).reshape(-1, k)
+        return jnp.asarray(rows)
 
     def _init_requester_geometry(self, topo) -> None:
         """Static per-requester index tables for the crossbar hot path.
@@ -464,10 +482,11 @@ class Simulator:
             # can rewrite rows mid-run.  jnp.array copies — never aliases
             # of the closure constants, which would be consumed with the
             # first donated chunk.
-            st["tbl_min"] = jnp.array(self.min_mask)
-            if self.away_mask is not None:
-                st["tbl_away"] = jnp.array(self.away_mask)
-            st["tbl_dist"] = jnp.array(self.dist.reshape(-1))
+            if self.fused:
+                st["tbl_rows"] = jnp.array(self.route_rows)
+            else:
+                st["tbl_min"] = jnp.array(self.min_mask)
+                st["tbl_dist"] = jnp.array(self.dist.reshape(-1))
             st["link_up"] = jnp.array(self.valid_port.reshape(-1))
             st["switch_up"] = jnp.ones(self.N, bool)
             st["fail_drop"] = Z()
@@ -481,6 +500,24 @@ class Simulator:
         Invalid ports are already zero in the packed words."""
         words = table[t_lr * self.N + cur]                       # [.,W]
         return ((words[:, self._w_idx] >> self._b_idx) & 1).astype(bool)
+
+    def _row_bits(self, rows, start: int):
+        """[len(rows), P] bool: bits ``start .. start+P-1`` of fused route
+        rows, decoded with static word slices and shifts."""
+        parts = []
+        for w in range(start // 32, (start + self.P - 1) // 32 + 1):
+            lo, hi = max(start, 32 * w), min(start + self.P, 32 * (w + 1))
+            shifts = np.arange(lo - 32 * w, hi - 32 * w, dtype=np.uint32)
+            parts.append((rows[:, w:w + 1] >> shifts) & 1)
+        return jnp.concatenate(parts, axis=1).astype(bool)
+
+    def _row_fields(self, table, leaf_rank, cur):
+        """``(toward [.,P], away [.,P], d [.] int16)`` of leaf ``leaf_rank``
+        at switch ``cur``: one fused route-row gather per requester."""
+        rows = table[leaf_rank * self.N + cur]                    # [.,K]
+        d = jax.lax.bitcast_convert_type(rows[:, -1], jnp.int32) >> 16
+        return (self._row_bits(rows, 0), self._row_bits(rows, self.P),
+                d.astype(jnp.int16))
 
     # ------------------------------------------------------------------ #
     def _inject(self, st, key, traffic: Traffic):
@@ -798,14 +835,12 @@ class Simulator:
                 # live tables from the state; live_row gates every policy's
                 # candidate set to live ports (dead switches contribute
                 # all-dead rows, so their packets freeze until drop/restore)
-                tmin = st["tbl_min"]
-                taway = st.get("tbl_away")
-                dflat = st["tbl_dist"]
+                trows = st.get("tbl_rows")
+                tmin = st.get("tbl_min")
                 live_row = st["link_up"].reshape(N, P)[cur]            # [NR,P]
             else:
+                trows = self.route_rows
                 tmin = self.min_mask
-                taway = self.away_mask
-                dflat = self.dist.reshape(-1)
                 live_row = None
             if pol == "polarized":
                 # full Polarized classification from toward/away bits alone:
@@ -813,12 +848,8 @@ class Simulator:
                 # (while d_cs < d_ct), Contraction = toward & toward (once
                 # d_cs >= d_ct); d(n,t) for the hop budget is d(c,t)+away-toward
                 s_lr = sd >> 16
-                dn_t = self._port_bits(tmin, t_lr, cur)
-                up_t = self._port_bits(taway, t_lr, cur)
-                dn_s = self._port_bits(tmin, s_lr, cur)
-                up_s = self._port_bits(taway, s_lr, cur)
-                d_ct = dflat[t_lr * N + cur]
-                d_cs = dflat[s_lr * N + cur]
+                dn_t, up_t, d_ct = self._row_fields(trows, t_lr, cur)
+                dn_s, up_s, d_cs = self._row_fields(trows, s_lr, cur)
                 src_side = (d_cs < d_ct)[:, None]
                 deroute = (up_s & up_t & src_side) | (dn_s & dn_t & ~src_side)
                 d_nt = (d_ct[:, None] + up_t.astype(jnp.int16)
@@ -832,12 +863,10 @@ class Simulator:
                 # away ports (one layer up, +2 hops round trip) within the hop
                 # budget.  On a pristine fabric the fallback never fires, so
                 # degraded == minimal_adaptive bit for bit.
-                toward = self._port_bits(tmin, t_lr, cur)
-                away = self._port_bits(taway, t_lr, cur)
+                toward, away, d_ct = self._row_fields(trows, t_lr, cur)
                 if hf:
                     toward = toward & live_row
                     away = away & live_row
-                d_ct = dflat[t_lr * N + cur]
                 no_min = ~jnp.any(toward, axis=1)
                 budget_ok = (hops[:, None] + 2 + d_ct[:, None]) <= self.cfg.max_hops
                 fallback = no_min[:, None] & away & budget_ok
@@ -1479,6 +1508,15 @@ class Simulator:
         live = np.arange(D)[None, :] < ln[:, None]
         return int(np.take_along_axis(sizes, idx, 1)[live].sum())
 
+    def _count_steps(self, slots: int) -> None:
+        """Count ``slots`` stepped slots (replicas summed) and the
+        routing-table rows their route phases gathered: 2 a requester and
+        sub-round for ``polarized`` (source and target row), 1 for the
+        rest.  Host arithmetic on static numbers, no device read."""
+        tracing.count(SLOTS_STEPPED, slots)
+        per = 2 if self.cfg.policy == "polarized" else 1
+        tracing.count(ROUTE_ROWS, slots * self.cfg.speedup * self.NR * per)
+
     @staticmethod
     def _counter_snapshot(st) -> dict:
         # fresh device buffers (`x + 0`), not views: the source state is
@@ -1492,7 +1530,7 @@ class Simulator:
         st = self.run_chunk(st, traffic, warm)
         base = self._counter_snapshot(st)
         st = self.run_chunk(st, traffic, measure)
-        tracing.count(SLOTS_STEPPED, warm + measure)
+        self._count_steps(warm + measure)
         # warm/measure deltas computed on device, fetched in ONE transfer
         # (the old path issued three blocking int() syncs per phase)
         m = jax.device_get({k: st[k] - base[k] for k in base}
@@ -1527,7 +1565,7 @@ class Simulator:
         st = chunk(st, warm)
         base = self._counter_snapshot(st)
         st = chunk(st, measure)
-        tracing.count(SLOTS_STEPPED, (warm + measure) * len(seeds))
+        self._count_steps((warm + measure) * len(seeds))
         m = jax.device_get({k: st[k] - base[k] for k in base}
                            | {"ejected_total": st["ejected"]})
         e, h = np.asarray(m["ejected"]), np.asarray(m["hop_sum"])
@@ -1546,7 +1584,7 @@ class Simulator:
         st = self.run_chunk(st, traffic, warm)
         base = st["lat_hist"] + 0            # fresh buffer; st is donated
         st = self.run_chunk(st, traffic, measure)
-        tracing.count(SLOTS_STEPPED, warm + measure)
+        self._count_steps(warm + measure)
         hist = np.asarray(jax.device_get(st["lat_hist"] - base))
         return {"hist": hist, **percentiles(hist, LATENCY_QS)}
 
@@ -1560,7 +1598,7 @@ class Simulator:
         st = self.run_chunk_batch(st, traffic, warm)
         base = st["lat_hist"] + 0
         st = self.run_chunk_batch(st, traffic, measure)
-        tracing.count(SLOTS_STEPPED, (warm + measure) * len(seeds))
+        self._count_steps((warm + measure) * len(seeds))
         hist = np.asarray(jax.device_get(st["lat_hist"] - base))  # [R, bins]
         per = [percentiles(row, LATENCY_QS) for row in hist]
         out = {"hist": hist}
@@ -1621,7 +1659,7 @@ class Simulator:
         st = self.run_chunk(st, traffic, warm)
         base = self._serving_snapshot(st)
         st = self.run_chunk(st, traffic, measure)
-        tracing.count(SLOTS_STEPPED, warm + measure)
+        self._count_steps(warm + measure)
         m = jax.device_get({k: st[k] - base[k] for k in base})
         return {**self._serving_metrics(m, self.S, measure), "state": st}
 
@@ -1637,7 +1675,7 @@ class Simulator:
         st = self.run_chunk_batch(st, traffic, warm)
         base = self._serving_snapshot(st)
         st = self.run_chunk_batch(st, traffic, measure)
-        tracing.count(SLOTS_STEPPED, (warm + measure) * len(seeds))
+        self._count_steps((warm + measure) * len(seeds))
         m = jax.device_get({k: st[k] - base[k] for k in base})
         return {**self._serving_metrics(m, self.S, measure), "state": st}
 
@@ -1671,16 +1709,19 @@ class Simulator:
                  + np.arange(n)[None, :]).reshape(-1).astype(np.int32))
             scatter = _scatter_rows_batch if batched else _scatter_rows
             with _quiet_cpu_donation():
-                st["tbl_min"] = scatter(
-                    st["tbl_min"], rows,
-                    jnp.asarray(delta.min_rows.reshape(k * n, w)))
-                if "tbl_away" in st:
-                    st["tbl_away"] = scatter(
-                        st["tbl_away"], rows,
-                        jnp.asarray(delta.away_rows.reshape(k * n, w)))
-                st["tbl_dist"] = scatter(
-                    st["tbl_dist"], rows,
-                    jnp.asarray(delta.dist_rows.reshape(-1)))
+                if self.fused:
+                    packed = pack_route_rows(delta.min_rows, delta.away_rows,
+                                             delta.dist_rows, self.P)
+                    st["tbl_rows"] = scatter(
+                        st["tbl_rows"], rows,
+                        jnp.asarray(packed.reshape(k * n, self.K)))
+                else:
+                    st["tbl_min"] = scatter(
+                        st["tbl_min"], rows,
+                        jnp.asarray(delta.min_rows.reshape(k * n, w)))
+                    st["tbl_dist"] = scatter(
+                        st["tbl_dist"], rows,
+                        jnp.asarray(delta.dist_rows.reshape(-1)))
         return st
 
     def drop_dead_packets(self, st):
@@ -1793,7 +1834,7 @@ class Simulator:
                                            "lat_hist")}
             st = apply_due(st, warm + measure)
             st = advance_to(st, warm + measure)
-            tracing.count(SLOTS_STEPPED, warm + measure)
+            self._count_steps(warm + measure)
             m = jax.device_get({k: st[k] - base[k] for k in base}
                                | {"ejected_total": st["ejected"]})
         finally:
@@ -2089,7 +2130,7 @@ class Simulator:
             if fresh and budget_chunks is None:
                 # whole chunks from slot 0: the final slot is what was
                 # stepped, per replica
-                tracing.count(SLOTS_STEPPED, int(final.sum()))
+                self._count_steps(int(final.sum()))
         else:
             slots = done.sum(axis=-1)
         completed = ok.all(axis=-1)

@@ -9,7 +9,7 @@ from repro.api import (
     build_network, expand_axes, open_simulator, register_topology, run,
     sweep, topology_families,
 )
-from repro.core import build_tables, mrls
+from repro.core import build_tables, mrls, route_row_words
 from repro.simulator.engine import SimConfig, Simulator, Traffic
 
 TINY = NetworkSpec("mrls", {"n_leaves": 14, "u": 3, "d": 3, "seed": 0})
@@ -480,15 +480,17 @@ def test_estimate_memory_exact_table_and_state_bytes():
     est = estimate_memory(TINY, ROUTE)
     tb = build_tables(build_network(TINY), masks="dense")
     assert est["tables"]["dist_leaf_bytes"] == tb.dist_leaf.nbytes
-    # polarized holds both device masks; dense layout retains both numpy
-    # twins on the host
-    assert est["tables"]["device_mask_bytes"] == (tb.min_mask.nbytes
-                                                 + tb.away_mask.nbytes)
+    # dense layout retains both numpy mask twins on the host
     assert est["tables"]["host_mask_bytes"] == (tb.min_mask.nbytes
                                                 + tb.away_mask.nbytes)
     assert est["tables"]["mask_layout"] == "dense"
     # state estimate == the real state's array bytes, exactly
     with Simulator(tb, ROUTE.to_sim_config()) as sim:
+        # polarized keeps one device table: the fused route rows
+        tables = {k: v for k, v in sim._tables().items() if v is not None}
+        assert set(tables) == {"route_rows"}
+        assert est["tables"]["device_table_bytes"] == \
+            tables["route_rows"].nbytes
         st = sim.make_state(Traffic("uniform", load=0.5), 0)
         counted = ("qbuf", "qhead", "qlen", "oq_buf", "oq_head", "oq_len",
                    "eq_buf", "eq_head", "eq_len", "fl_buf", "p_sd",
@@ -509,11 +511,16 @@ def test_estimate_memory_from_experiment_and_replicas():
     assert est["replicas"] == 4
     assert (est["total_bytes"] - est1["total_bytes"]
             == 3 * est1["state_bytes_per_replica"])
-    # minimal policies hold one device mask, not two
+    # minimal policies hold the toward-bit words and int16 distances of a
+    # (leaf, switch) pair; polarized one fused row of route_row_words
     est_min = estimate_memory(TINY, RouteSpec(policy="minimal_adaptive",
                                               pool=4096))
-    assert (est_min["tables"]["device_mask_bytes"] * 2
-            == est["tables"]["device_mask_bytes"])
+    dims = est["dims"]
+    pairs = dims["n_leaves"] * dims["n_switches"]
+    assert (est_min["tables"]["device_table_bytes"]
+            == pairs * (4 * dims["mask_words"] + 2))
+    assert (est["tables"]["device_table_bytes"]
+            == pairs * 4 * route_row_words(dims["max_ports"]))
 
 
 def test_estimate_memory_prices_failure_schedule_state():
@@ -538,9 +545,9 @@ def test_estimate_memory_prices_failure_schedule_state():
     tb = build_tables(topo, masks="dense")
     with Simulator(tb, ROUTE.to_sim_config(), failures=sched) as sim:
         st = sim.make_state(Traffic("uniform", load=0.5), 0)
-        extra = ("tbl_min", "tbl_away", "tbl_dist", "link_up", "switch_up",
-                 "fail_drop")
+        extra = ("tbl_rows", "link_up", "switch_up", "fail_drop")
         assert set(extra) <= set(st)
+        assert not {"tbl_min", "tbl_away", "tbl_dist"} & set(st)
         actual = sum(np.asarray(st[k]).nbytes for k in extra)
     assert add_on == actual
 

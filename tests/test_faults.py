@@ -356,6 +356,42 @@ def test_drop_policy_frees_stranded_packets():
     assert 0.0 < r["throughput"] <= 1.0
 
 
+@pytest.mark.parametrize("batched", [False, True])
+def test_armed_polarized_update_tables_writes_repacked_delta_rows(batched):
+    """Armed polarized state holds one fused route-row table; after
+    update_tables its affected rows are the delta repacked, the others
+    untouched, and the whole table equals a fresh pack of the updated host
+    tables (replica for replica when batched)."""
+    from repro.core import pack_route_rows
+
+    tb = build_tables(TOPO, masks="dense")
+    p = TOPO.max_ports
+    events = _link_events(TOPO, 4, seed=2)
+    cfg = SimConfig(policy="polarized", max_hops=10, pool=4096)
+    tr = Traffic("uniform", load=0.5)
+    with Simulator(tb, cfg,
+                   failures=FailureSchedule(events=events)) as sim:
+        st = (sim.make_batch_state(tr, [0, 1]) if batched
+              else sim.make_state(tr, 0))
+        assert "tbl_rows" in st
+        assert not {"tbl_min", "tbl_away", "tbl_dist"} & set(st)
+        shape = (-1, sim.n1, sim.N, sim.K)
+        before = np.asarray(st["tbl_rows"]).reshape(shape)
+        delta = tb.apply_failures(down=events)
+        assert 0 < delta.n_affected < sim.n1
+        st = sim.update_tables(st, delta)
+        got = np.asarray(st["tbl_rows"]).reshape(shape)
+    assert got.shape[0] == (2 if batched else 1)
+    want = pack_route_rows(delta.min_rows, delta.away_rows, delta.dist_rows,
+                           p)
+    other = np.setdiff1d(np.arange(TOPO.n_leaves), delta.leaf_rows)
+    for rep in got:
+        np.testing.assert_array_equal(rep[delta.leaf_rows], want)
+        np.testing.assert_array_equal(rep[other], before[0][other])
+        np.testing.assert_array_equal(
+            rep, pack_route_rows(tb.min_mask, tb.away_mask, tb.dist_leaf, p))
+
+
 def test_failure_apis_require_armed_simulator():
     tb = build_tables(TOPO)
     with Simulator(tb, SimConfig(policy="polarized", pool=4096)) as sim:

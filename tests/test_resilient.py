@@ -138,8 +138,9 @@ def test_runner_hard_wedge_fails_fast(tmp_path):
 # checkpoint round-trips of engine state
 # ---------------------------------------------------------------------- #
 def test_armed_state_checkpoint_roundtrip(tmp_path):
-    # armed simulator: state carries int16 distance tables, uint32 mask
-    # words, the free-list ring, and live link_up/fail_drop
+    # armed simulator: state carries the fused uint32 route rows (int16
+    # distances in their top halves), the free-list ring, and live
+    # link_up/fail_drop
     topo = build_network(NET)
     sched = FailureSchedule.random_links(topo, 2, down_slot=3, seed=0)
     s = Simulator(build_tables(topo), ROUTE.to_sim_config(seed=0),
@@ -147,8 +148,8 @@ def test_armed_state_checkpoint_roundtrip(tmp_path):
     tr = Traffic("all2all", rounds=2)
     st = s.run_chunk(s.make_state(tr, 0), tr, 8)   # past down_slot
     host = {k: np.asarray(v) for k, v in jax.device_get(st).items()}
-    assert host["tbl_dist"].dtype == np.int16
-    assert host["tbl_min"].dtype == np.uint32
+    assert host["tbl_rows"].dtype == np.uint32
+    assert host["tbl_rows"].shape == (s.n1 * s.N, s.K)
     assert host["link_up"].dtype == np.bool_
 
     ck = Checkpointer(str(tmp_path))

@@ -177,3 +177,99 @@ def test_build_tables_auto_layout_threshold(monkeypatch):
     assert build_tables(t).mask_layout == "blocked"
     with pytest.raises(ValueError, match="mask layout"):
         build_tables(t, masks="sparse")
+
+
+# ---------------------------------------------------------------------- #
+# fused route rows: toward bits, away bits and distance in one row
+# ---------------------------------------------------------------------- #
+def unpack_route_rows(rows, p):
+    """``(min_words, away_words, dist)`` of fused rows, decoded bit by bit
+    on the host: the reference the packing is held to."""
+    w = (p + 31) // 32
+    bits = np.unpackbits(rows.astype("<u4").view(np.uint8), axis=-1,
+                         bitorder="little")
+    pad = np.zeros(bits.shape[:-1] + (32 * w - p,), np.uint8)
+
+    def words(field):
+        b = np.concatenate([field, pad], axis=-1)
+        return np.packbits(b, axis=-1, bitorder="little").view("<u4") \
+            .astype(np.uint32)
+
+    top = bits[..., 32 * rows.shape[-1] - 16:]
+    dist = np.packbits(top, axis=-1, bitorder="little").view("<i2")[..., 0]
+    return words(bits[..., :p]), words(bits[..., p:2 * p]), dist
+
+
+@pytest.mark.parametrize("masks,block", [("dense", 256), ("blocked", 1),
+                                         ("blocked", 5), ("blocked", 256)])
+def test_route_rows_round_trip_of_the_tables(masks, block):
+    """Packed block by block in either layout, the fused rows unpack to the
+    dense toward words, away words and int16 distances; at radix 36 a row
+    is 3 words and the away field straddles words 1 and 2."""
+    from repro.core import (build_tables, mrls, pack_route_rows,
+                            route_row_words)
+
+    t = mrls(24, u=18, d=18, seed=0)
+    p = t.max_ports
+    assert p == 36 and route_row_words(p) == 3
+    dense = build_tables(t, masks="dense")
+    tb = build_tables(t, masks=masks, leaf_block=block)
+    covered = 0
+    for lo, hi, min_b, away_b in tb.mask_blocks():
+        rows = pack_route_rows(min_b, away_b, tb.dist_leaf[lo:hi], p)
+        assert rows.shape == (hi - lo, t.n_switches, 3)
+        m, a, d = unpack_route_rows(rows, p)
+        np.testing.assert_array_equal(m, dense.min_mask[lo:hi])
+        np.testing.assert_array_equal(a, dense.away_mask[lo:hi])
+        np.testing.assert_array_equal(d, dense.dist_leaf[lo:hi])
+        assert d.dtype == np.int16
+        covered += hi - lo
+    assert covered == t.n_leaves
+
+
+@pytest.mark.parametrize("p", [1, 6, 31, 32, 36, 48, 64])
+def test_route_rows_round_trip_of_any_width(p):
+    """Every port count: random bit words and int16 distances (the
+    UNREACHABLE sentinel and negatives included) survive the packing."""
+    from repro.core import UNREACHABLE, pack_route_rows, route_row_words
+
+    rng = np.random.default_rng(p)
+    w = (p + 31) // 32
+    top = np.uint32((1 << (p - 32 * (w - 1))) - 1)    # ports past p are 0
+
+    def words():
+        x = rng.integers(0, 1 << 32, (40, w), dtype=np.uint64).astype(
+            np.uint32)
+        x[:, -1] &= top
+        return x
+
+    mw, aw = words(), words()
+    dist = rng.integers(-(1 << 15), 1 << 15, 40).astype(np.int16)
+    dist[:3] = (UNREACHABLE, 0, -1)
+    rows = pack_route_rows(mw, aw, dist, p)
+    assert rows.shape == (40, route_row_words(p))
+    assert 32 * rows.shape[1] >= 2 * p + 16
+    m, a, d = unpack_route_rows(rows, p)
+    np.testing.assert_array_equal(m, mw)
+    np.testing.assert_array_equal(a, aw)
+    np.testing.assert_array_equal(d, dist)
+
+
+def test_route_rows_round_trip_of_a_delta_with_unreachable_rows():
+    """A failed leaf switch cuts every leaf off from it: the delta's rows
+    carry UNREACHABLE, and their fused rows keep it exactly."""
+    from repro.core import (UNREACHABLE, FailureEvent, build_tables, mrls,
+                            pack_route_rows)
+
+    t = mrls(24, u=18, d=18, seed=0)
+    tb = build_tables(t, masks="blocked", leaf_block=7)
+    delta = tb.apply_failures(down=(FailureEvent("switch",
+                                                 int(t.leaf_ids[3]), 0),))
+    assert delta.n_affected == t.n_leaves
+    assert (delta.dist_rows == UNREACHABLE).any()
+    rows = pack_route_rows(delta.min_rows, delta.away_rows, delta.dist_rows,
+                           t.max_ports)
+    m, a, d = unpack_route_rows(rows, t.max_ports)
+    np.testing.assert_array_equal(m, delta.min_rows)
+    np.testing.assert_array_equal(a, delta.away_rows)
+    np.testing.assert_array_equal(d, delta.dist_rows)

@@ -10,8 +10,8 @@ import pytest
 from repro.api import Experiment, SimulatorCache, registry, run
 from repro.core import build_tables, mrls
 from repro.runtime import tracing
-from repro.simulator.engine import SLOTS_STEPPED, SimConfig, Simulator, \
-    Traffic
+from repro.simulator.engine import ROUTE_ROWS, SLOTS_STEPPED, SimConfig, \
+    Simulator, Traffic
 from repro.workloads import all2all_program, compile_program
 
 TINY = {"family": "mrls", "params": {"n_leaves": 14, "u": 3, "d": 3,
@@ -169,6 +169,27 @@ def _program_hlo(sim):
     return Simulator._program_loop.lower(
         sim, st, sim._tables(), sim.program_traffic(cp), 4,
         4000).compile().as_text()
+
+
+@pytest.mark.parametrize("policy,rows", [("polarized", 2),
+                                         ("minimal_adaptive", 1)])
+def test_route_rows_per_requester_and_sub_round(tables, policy, rows):
+    """``engine.route_rows``: table rows the route phase gathered, 2 a
+    requester and crossbar sub-round under polarized (source and target),
+    1 under the minimal policies; counted beside ``engine.slots_stepped``."""
+    with Simulator(tables, SimConfig(policy=policy, max_hops=10,
+                                     pool=4096)) as sim:
+        with tracing.record() as rec:
+            sim.run_throughput(Traffic("uniform", load=0.5), warm=3,
+                               measure=4, seed=0)
+            sim.run_throughput_batch(Traffic("uniform", load=0.5), [0, 1],
+                                     warm=3, measure=4)
+        requesters = sim.NR
+    assert requesters == tables.topo.n_switches * tables.topo.max_ports \
+        + tables.topo.n_endpoints
+    assert rec.counts[SLOTS_STEPPED] == 7 + 2 * 7
+    assert rec.counts[ROUTE_ROWS] == (rec.counts[SLOTS_STEPPED] * 2
+                                      * requesters * rows)
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])
