@@ -274,6 +274,12 @@ class Experiment:
     ``seed .. seed+R-1`` through one ``jax.vmap``-batched executable (one
     compile, no per-replica host round-trips) and the :class:`Result`
     carries per-replica values plus mean/std/min/max aggregates.
+
+    ``chunk`` sets two things of a collective: how many slots the device
+    loop steps between its tests of ``max_slots`` (a run that times out
+    stops on a chunk boundary), and the unit of a checkpointed run's
+    segments (``--ckpt-every`` chunks).  It does not set where a
+    collective program stops: that loop ends at the completion slot.
     """
 
     network: NetworkSpec

@@ -1987,15 +1987,28 @@ class Simulator:
         batch.update(shared)
         return batch
 
-    @functools.partial(jax.jit, static_argnums=(0, 3, 4, 5),
+    @functools.partial(jax.jit, static_argnums=(0, 3, 4, 5, 6),
                        donate_argnums=(1,))
     def _program_loop(self, st, tb, traffic: Traffic, chunk: int,
-                      max_slots: int):
+                      max_slots: int, budget: Optional[int] = None):
         """Device-side program executor: one ``lax.while_loop`` drives all
         phases of all replicas — the phase counter, per-phase ejection
         targets, and exact completion slots all live on device, so an
         R-replica, P-phase collective is one device computation with zero
-        per-phase host round-trips."""
+        per-phase host round-trips.
+
+        Each chunk body steps up to ``chunk`` slots and ends at the slot
+        where the program completes (every replica, when batched), so the
+        final state's ``slot`` is the completion slot, not the next chunk
+        boundary.  ``max_slots`` is tested between chunk bodies only: a run
+        that times out stops on a chunk boundary.
+
+        ``budget=B`` runs at most ``B`` chunk bodies, then returns control
+        to the host — the checkpointable chunk boundary for resumable
+        collective runs.  The budget only counts chunk bodies, so a chain
+        of bounded segments, including segments re-entered from a restored
+        snapshot, replays the unbounded loop bitwise.
+        """
         batched = st["ejected"].ndim == 1
         sim = self._bound(tb)
         step = lambda s: sim._step(s, traffic, chunk=chunk,
@@ -2009,62 +2022,32 @@ class Simulator:
                     else 0 for k in st}
             step = jax.vmap(step, in_axes=(axes,), out_axes=axes)
 
-        def chunk_body(s):
-            return jax.lax.scan(lambda c, _: (step(c), None), s, None,
-                                length=chunk)[0]
-
         if traffic.schedule == "window":
-            def cond(s):
-                running = ~jnp.all(s["phase_done"][..., -1] >= 0)
-                return running & (jnp.max(s["slot"]) < max_slots)
+            def running(s):
+                return ~jnp.all(s["phase_done"][..., -1] >= 0)
         else:
-            # barrier phases force-advance at their chunk-granular
-            # max_slots budget, so the phase counter always reaches
-            # n_phases eventually
-            def cond(s):
+            def running(s):
                 return ~jnp.all(s["phase"] >= traffic.n_phases)
-
-        return jax.lax.while_loop(cond, chunk_body, st)
-
-    @functools.partial(jax.jit, static_argnums=(0, 3, 4, 5, 6),
-                       donate_argnums=(1,))
-    def _program_loop_bounded(self, st, tb, traffic: Traffic, chunk: int,
-                              max_slots: int, budget: int):
-        """:meth:`_program_loop` with a chunk *budget*: at most ``budget``
-        chunk bodies per call, then control returns to the host — the
-        checkpointable chunk boundary for resumable collective runs.  The
-        chunk body and the program-completion condition are byte-for-byte
-        the unbounded loop's (the budget only adds an iteration counter to
-        the carry), so a chain of bounded segments — including segments
-        re-entered from a restored snapshot — replays the uninterrupted
-        ``run_program`` bitwise.
-        """
-        batched = st["ejected"].ndim == 1
-        sim = self._bound(tb)
-        step = lambda s: sim._step(s, traffic, chunk=chunk,
-                                   max_slots=max_slots)
-        if batched:
-            axes = {k: None if st[k].ndim == self._PROG_SHARED.get(k, -1)
-                    else 0 for k in st}
-            step = jax.vmap(step, in_axes=(axes,), out_axes=axes)
 
         def chunk_body(carry):
             s, it = carry
-            s = jax.lax.scan(lambda c, _: (step(c), None), s, None,
-                             length=chunk)[0]
+            s, _ = jax.lax.while_loop(
+                lambda c: (c[1] < chunk) & running(c[0]),
+                lambda c: (step(c[0]), c[1] + 1),
+                (s, jnp.zeros((), jnp.int32)))
             return s, it + 1
-
-        if traffic.schedule == "window":
-            def running(s):
-                live = ~jnp.all(s["phase_done"][..., -1] >= 0)
-                return live & (jnp.max(s["slot"]) < max_slots)
-        else:
-            def running(s):
-                return ~jnp.all(s["phase"] >= traffic.n_phases)
 
         def cond(carry):
             s, it = carry
-            return running(s) & (it < budget)
+            go = running(s)
+            if traffic.schedule == "window":
+                go &= jnp.max(s["slot"]) < max_slots
+            # a stuck barrier phase force-advances at its chunk-granular
+            # max_slots budget, so the phase counter always reaches
+            # n_phases without a slot test here
+            if budget is not None:
+                go &= it < budget
+            return go
 
         st, _ = jax.lax.while_loop(cond, chunk_body,
                                    (st, jnp.zeros((), jnp.int32)))
@@ -2092,8 +2075,12 @@ class Simulator:
         per-phase durations under ``barrier``, cumulative completion slots
         under ``window``); per-replica arrays when batched.
 
-        ``budget_chunks=B`` bounds one call to at most ``B`` chunk bodies
-        (the checkpointable segment used by
+        The device loop stops at the slot where the program completes (the
+        slowest replica's, when batched); it steps nothing past it.
+        ``chunk`` sets only how often ``max_slots`` is tested (a run that
+        times out stops on a chunk boundary) and the unit of a segment:
+        ``budget_chunks=B`` bounds one call to at most
+        ``B`` chunk bodies (the checkpointable segment used by
         :mod:`repro.runtime.resilient`); the result then carries
         ``running`` — True while the program has phases left — and the
         other fields are partial until it flips False.  A chain of bounded
@@ -2113,13 +2100,9 @@ class Simulator:
             st = state
         st = {k: jnp.asarray(v) for k, v in st.items()}
         with _quiet_cpu_donation():
-            if budget_chunks is None:
-                st = self._program_loop(st, self._tables(), traffic, chunk,
-                                        max_slots)
-            else:
-                st = self._program_loop_bounded(st, self._tables(), traffic,
-                                                chunk, max_slots,
-                                                int(budget_chunks))
+            st = self._program_loop(
+                st, self._tables(), traffic, chunk, max_slots,
+                None if budget_chunks is None else int(budget_chunks))
         done = np.asarray(st["phase_done"])
         ok = np.asarray(st["phase_ok"])
         if traffic.schedule == "window":
@@ -2128,8 +2111,9 @@ class Simulator:
             done = np.where(done >= 0, done, final)
             slots = done[..., -1]
             if fresh and budget_chunks is None:
-                # whole chunks from slot 0: the final slot is what was
-                # stepped, per replica
+                # the loop stops at the slowest replica's completion slot
+                # (or a timed-out chunk boundary): the final slot is what
+                # was stepped, per replica
                 self._count_steps(int(final.sum()))
         else:
             slots = done.sum(axis=-1)

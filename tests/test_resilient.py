@@ -192,6 +192,36 @@ def test_program_bounded_equals_unbounded(sim, program):
     _tree_equal(jax.device_get(r["state"]), jax.device_get(ref["state"]))
 
 
+def test_program_bounded_equals_unbounded_mid_chunk(sim, program):
+    # completion inside a chunk: both loops stop at the completion slot
+    ref = sim.run_program(program, chunk=4, max_slots=2000, seed=0)
+    assert ref["completed"] and ref["slots"] % 4
+    st, running, segments = None, True, 0
+    while running:
+        r = sim.run_program(program, chunk=4, max_slots=2000, seed=0,
+                            state=st, budget_chunks=1)
+        st, running = r["state"], r["running"]
+        segments += 1
+    assert segments == -(-ref["slots"] // 4) > 1
+    assert r["slots"] == ref["slots"]
+    assert r["completed"] == ref["completed"]
+    assert r["pool_stall"] == ref["pool_stall"]
+    assert tuple(r["phase_slots"]) == tuple(ref["phase_slots"])
+    final = jax.device_get(r["state"])
+    _tree_equal(final, jax.device_get(ref["state"]))
+    assert int(final["slot"]) == ref["slots"]
+
+
+@pytest.mark.parametrize("max_slots", [3, 6])
+def test_window_timeout_stops_on_a_chunk_boundary(sim, program, max_slots):
+    # max_slots is tested between chunk bodies only
+    r = sim.run_program(program, chunk=4, max_slots=max_slots, seed=0)
+    assert not r["completed"]
+    rounded = 4 * -(-max_slots // 4)
+    assert r["slots"] == rounded
+    assert int(jax.device_get(r["state"]["slot"])) == rounded
+
+
 def test_completion_bounded_equals_unbounded(sim):
     tr = Traffic("all2all", rounds=2)
     expected = sim.S * 2

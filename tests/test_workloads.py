@@ -1,5 +1,6 @@
 """repro.workloads: pattern registry, program IR/compiler invariants, and
 the engine's on-device program executor."""
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -237,6 +238,59 @@ def test_program_batch_matches_scalar_bitwise(sim):
         assert list(rb["phase_slots"][i]) == list(rs["phase_slots"])
         assert int(rb["slots"][i]) == rs["slots"]
         assert bool(rb["completed"][i]) == rs["completed"]
+
+
+@pytest.fixture(scope="module")
+def chunk_runs(sim):
+    """``run_program`` outputs and final-state counters, memoised per
+    (schedule, replicas, chunk): a windowed all2all and a barrier
+    Rabenseifner allreduce, scalar (seed 5) and two replicas (3, 4)."""
+    programs = {
+        "window": compile_program(all2all_program(sim.S, 4),
+                                  schedule="window", window=2),
+        "barrier": compile_program(rabenseifner_program(sim.S, 16, 8))}
+    memo = {}
+
+    def get(schedule, replicas, chunk):
+        key = (schedule, replicas, chunk)
+        if key not in memo:
+            seeds = dict(seeds=[3, 4]) if replicas == 2 else dict(seed=5)
+            r = sim.run_program(programs[schedule], chunk=chunk,
+                                max_slots=4000, **seeds)
+            st = jax.device_get(r.pop("state"))
+            counters = {k: np.asarray(st[k])
+                        for k in ("created", "ejected", "hop_sum", "slot")}
+            counters["queued"] = np.asarray(
+                st["eq_len"].sum(-1) + st["qlen"].sum(-1)
+                + st["oq_len"].sum(-1))
+            memo[key] = r, counters
+        return memo[key]
+    return get
+
+
+@pytest.mark.parametrize("chunk", [4, 8, 16, 64])
+@pytest.mark.parametrize("replicas", [1, 2])
+@pytest.mark.parametrize("schedule", ["window", "barrier"])
+def test_program_stops_at_its_completion_slot_for_every_chunk(
+        chunk_runs, schedule, replicas, chunk):
+    # chunk 1 tests for completion after every slot: the reference
+    ref, ref_counters = chunk_runs(schedule, replicas, 1)
+    r, counters = chunk_runs(schedule, replicas, chunk)
+    assert np.all(ref["completed"])
+    for k in ("slots", "phase_slots", "pool_stall", "completed"):
+        np.testing.assert_array_equal(r[k], ref[k], err_msg=k)
+    for k in ("created", "ejected", "hop_sum", "queued"):
+        np.testing.assert_array_equal(counters[k], ref_counters[k],
+                                      err_msg=k)
+    slots = np.asarray(r["slots"])
+    if schedule == "window":
+        # every replica stepped to the slowest one's completion slot
+        np.testing.assert_array_equal(counters["slot"],
+                                      np.full_like(slots, slots.max()))
+    else:
+        # the last phase's completion resets the slot; a replica that
+        # finished early keeps stepping until the slowest one completes
+        np.testing.assert_array_equal(counters["slot"], slots.max() - slots)
 
 
 def test_program_endpoint_count_must_match_fabric(sim):
